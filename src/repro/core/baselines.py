@@ -51,7 +51,7 @@ BASELINES = {
 
 
 def make_engine(cfg: ModelConfig, params, spec: BaselineSpec, *, capacity: int,
-                hw: HardwareProfile = HardwareProfile(), lora=None,
+                hw: Optional[HardwareProfile] = None, lora=None,
                 lora_scale: float = 1.0) -> OffloadedMoEEngine:
     E = cfg.moe_spec.num_experts
     return OffloadedMoEEngine(

@@ -58,6 +58,7 @@ from ..models.moe import (Dispatch, combine_tokens, dispatch_tokens,
 from ..models.runtime import Runtime
 from ..obs.trace import get_tracer
 from ..faults import FetchPolicy, get_fault_plan
+from ..kernels.dispatch import record
 from .expert_cache import ModelExpertCache
 from .little_expert import LittleExpertBank
 from .quant import (QTensor, dequantize_linear, matmul_layout, qmatmul,
@@ -92,6 +93,9 @@ def _quiet_donation(fn):
 
 @dataclass(frozen=True)
 class HardwareProfile:
+    """Eq.-3 constants. The defaults are the documented target, one TPU
+    v5e chip (peaks below); the link, latency, host and efficiency terms
+    are assumptions, not measurements."""
     name: str = "tpu-v5e"
     peak_flops: float = 197e12  # bf16
     hbm_bw: float = 819e9
@@ -104,6 +108,28 @@ class HardwareProfile:
 PCIE5_H100 = HardwareProfile(
     name="h100-pcie5", peak_flops=989e12, hbm_bw=3350e9, host_link_bw=64e9
 )
+
+# Published per-chip peaks, keyed by jax's ``device_kind``. TPU v5e:
+# 197 TFLOP/s bf16 and 819 GB/s HBM (Google Cloud documentation, "TPU v5e").
+TPU_PEAKS = {
+    "TPU v5 lite": {"name": "tpu-v5e", "peak_flops": 197e12, "hbm_bw": 819e9},
+}
+
+
+def hardware_profile(device=None) -> HardwareProfile:
+    """The Eq.-3 profile for ``device`` (default: the first JAX device).
+    A TPU takes its peaks from :data:`TPU_PEAKS` by ``device_kind``, and
+    a kind not in the table is an error, never a guess. Any other
+    platform gets the documented v5e target profile."""
+    device = device or jax.devices()[0]
+    if device.platform != "tpu":
+        return HardwareProfile()
+    try:
+        return HardwareProfile(**TPU_PEAKS[device.device_kind])
+    except KeyError:
+        raise ValueError(
+            f"no Eq.-3 peaks for TPU kind {device.device_kind!r}; add its "
+            f"published numbers to TPU_PEAKS") from None
 
 
 # ---------------------------------------------------------------------------
@@ -408,7 +434,7 @@ class OffloadedMoEEngine:
         gamma: float = 0.9,
         quantized: bool = False,
         quant_group: int = 32,
-        hw: HardwareProfile = HardwareProfile(),
+        hw: Optional[HardwareProfile] = None,
         cpu_execute: bool = False,
         stream_all: bool = False,
         lora=None,
@@ -426,7 +452,7 @@ class OffloadedMoEEngine:
         self.cfg = cfg
         self.rt = Runtime(zero_drop=True, kernel_backend=kernel_backend)
         self.kernel_backend = kernel_backend
-        self.hw = hw
+        self.hw = hw or hardware_profile()
         self.capacity = capacity
         self.quantized = quantized
         self.quant_group = quant_group
@@ -767,11 +793,12 @@ class OffloadedMoEEngine:
                               for k in ("wg", "wu", "wd"))
             else:
                 wg, wu, wd = slabs["wg"], slabs["wu"], slabs["wd"]
+            record("moe_gmm", choice)
             if choice.use_pallas:
                 from ..kernels.moe_gmm import ops as gmm_ops
 
-                mm = partial(gmm_ops.gmm, backend="pallas",
-                             interpret=choice.interpret, group_sizes=sizes)
+                mm = partial(gmm_ops.gmm_pallas, group_sizes=sizes,
+                             interpret=choice.interpret)
             else:
                 mm = lambda a, w: jnp.einsum("cnd,cdf->cnf", a, w)
             hg = mm(buf, wg)

@@ -140,25 +140,19 @@ def qmatmul(x: jax.Array, ql, *, backend: Optional[str] = None,
 
     backend "ref" multiplies by the dequantized weight; "pallas"/"auto"
     runs the fused dequant matmul kernel (interpret mode off-TPU)."""
-    from ..kernels.dispatch import resolve
-    from ..kernels.int4_matmul.ops import MatmulQWeight, int4_matmul
+    from ..kernels.dispatch import record, resolve
+    from ..kernels.int4_matmul.ops import int4_matmul
 
     choice = resolve("int4_matmul", backend or "auto", interpret=interpret)
     if isinstance(ql, QTensor):
         if not choice.use_pallas:
+            record("int4_matmul", choice)
             return x @ dequantize_linear(ql, jnp.float32).astype(x.dtype)
         mq = matmul_layout(ql)
     else:
         mq = ql
-    if not choice.use_pallas:
-        from ..kernels.int4_matmul.ref import int4_matmul_ref
-
-        lead = x.shape[:-1]
-        out = int4_matmul_ref(x.reshape(-1, x.shape[-1]), mq.packed, mq.scale,
-                              mq.zero, mq.group)
-        return out.reshape(*lead, -1)
     return int4_matmul(x, mq.packed, mq.scale, mq.zero, group=mq.group,
-                       backend="pallas", interpret=choice.interpret)
+                       backend=choice.backend, interpret=choice.interpret)
 
 
 def quant_error(w: jax.Array, qt: QTensor) -> float:

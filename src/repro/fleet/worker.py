@@ -52,6 +52,7 @@ from ..serving import (
     OffloadedWaveServer,
     RequestQueue,
     get_scheduler,
+    prefill_expert_scores,
 )
 from ..serving.metrics import ServerMetrics
 from .heartbeat import HEARTBEAT_NAME, HeartbeatWriter
@@ -80,11 +81,12 @@ def write_results(path, results: Dict[int, object], mt, *,
 
 
 def poll_inbox(wdir: Path, enqueued: Set[int], queue: RequestQueue,
-               jr: RequestJournal) -> int:
+               jr: RequestJournal, score=None) -> int:
     """Consume supervisor re-offers: each inbox file is a JSON list of
     request records. The arrival is journaled (flushed) before the file
     is unlinked — a kill between the two replays as a duplicate offer,
-    which the seen-rid dedupe absorbs."""
+    which the seen-rid dedupe absorbs. ``score`` (offloaded workers)
+    annotates requests that arrive without expert scores."""
     inbox = wdir / "inbox"
     if not inbox.is_dir():
         return 0
@@ -98,6 +100,8 @@ def poll_inbox(wdir: Path, enqueued: Set[int], queue: RequestQueue,
             req = record_request(rec)
             if req.rid in enqueued:
                 continue
+            if score is not None and req.expert_scores is None:
+                score([req])
             jr.arrival(req)
             queue.push(req)
             enqueued.add(req.rid)
@@ -158,7 +162,13 @@ def main(argv=None) -> int:
                          cfg, jnp.float32)
     mode = spec.get("mode", "continuous")
     scheduler = get_scheduler(spec.get("scheduler", "fcfs"))
+    score = None
     if mode == "wave":
+        # oracle expert profiles for prefetch and affinity scheduling
+        def score(reqs):
+            prefill_expert_scores(cfg, params, reqs)
+
+        score([r for r in pending if r.expert_scores is None])
         srv = OffloadedWaveServer(
             cfg, params,
             capacity=int(spec.get("capacity") or cfg.melinoe_cache_capacity()),
@@ -191,7 +201,7 @@ def main(argv=None) -> int:
             hang_s = plan.maybe_hang()
             if hang_s > 0.0:
                 time.sleep(hang_s)  # wedged: no beat, no progress
-        poll_inbox(wdir, enqueued, queue, jr)
+        poll_inbox(wdir, enqueued, queue, jr, score)
         steps["total"] += 1
         last.update(now=info["now"], backlog=info["backlog"],
                     in_flight=info["in_flight"])
@@ -204,7 +214,7 @@ def main(argv=None) -> int:
     first_pass = True
     try:
         while True:
-            poll_inbox(wdir, enqueued, queue, jr)
+            poll_inbox(wdir, enqueued, queue, jr, score)
             if not len(queue):
                 if drain["flag"]:
                     break

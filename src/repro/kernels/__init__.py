@@ -7,8 +7,8 @@ interpret=True mode on CPU; see tests/test_kernels.py):
   flash_attn  — causal GQA flash attention fwd (prefill; VMEM-resident KV)
 
 ``dispatch`` owns backend selection (ref | pallas | auto), platform
-autodetection (interpret off-TPU) and the pltpu.CompilerParams
-version-compat shim shared by all four families.
+autodetection (interpret off-TPU) and the ``kernel_dispatch_total``
+counter of what each call site ran.
 """
 from . import dispatch, flash_attn, int4_matmul, moe_gmm, ssd_scan
 

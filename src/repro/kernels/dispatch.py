@@ -2,16 +2,6 @@
 hot path runs the Pallas kernel or the pure-jnp reference, and in which
 execution mode.
 
-Three concerns the kernel families previously hand-threaded (and got
-wrong — the `pltpu.CompilerParams` AttributeError hid the whole layer):
-
-  * JAX version compat — the pinned 0.4.x exposes
-    ``pltpu.TPUCompilerParams``; newer releases renamed it to
-    ``pltpu.CompilerParams`` (and dropped ``dimension_semantics``).
-    :func:`compiler_params` returns the right kwargs for ``pl.pallas_call``
-    on whatever is installed, degrading to "no params" when neither
-    exists (pure interpret-mode environments).
-
   * platform autodetection — compiled Pallas on TPU, ``interpret=True``
     everywhere else, so callers never pass ``interpret=`` by hand.
 
@@ -22,6 +12,10 @@ wrong — the `pltpu.CompilerParams` AttributeError hid the whole layer):
     shapes: compiled on TPU, interpret elsewhere". The environment
     variable ``REPRO_KERNEL_BACKEND`` overrides whatever the caller
     (usually ``Runtime.kernel_backend``) configured.
+
+  * the ``kernel_dispatch_total`` counter — :func:`record` books the
+    backend a call site actually traced, after any shape bail-out, so
+    a kernel that fell back to the reference is never counted as run.
 """
 from __future__ import annotations
 
@@ -39,51 +33,19 @@ BACKENDS = ("ref", "pallas", "auto")
 ENV_VAR = "REPRO_KERNEL_BACKEND"
 
 
-# ---------------------------------------------------------------------------
-# JAX version-compat shim
-# ---------------------------------------------------------------------------
-
-
-def _compiler_params_cls():
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-    except ImportError:  # pragma: no cover - pallas always present in-tree
-        return None
-    return getattr(pltpu, "TPUCompilerParams", None) or getattr(
-        pltpu, "CompilerParams", None
-    )
-
-
-def compiler_params(dimension_semantics=None, **kw) -> dict:
-    """Version-portable ``compiler_params=`` kwargs for ``pl.pallas_call``.
-
-    Usage: ``pl.pallas_call(..., **compiler_params(dimension_semantics=(...)))``.
-    Returns ``{}`` when no params class exists or when the installed class
-    rejects the requested fields (they are performance hints, never
-    correctness requirements).
-    """
-    cls = _compiler_params_cls()
-    if cls is None:
-        return {}
-    if dimension_semantics is not None:
-        kw = dict(kw, dimension_semantics=tuple(dimension_semantics))
-    try:
-        return {"compiler_params": cls(**kw)}
-    except TypeError:
-        kw.pop("dimension_semantics", None)
-        try:
-            return {"compiler_params": cls(**kw)} if kw else {}
-        except TypeError:
-            return {}
-
-
-def pick_tile(v: int, pref: int) -> int:
-    """Largest divisor of ``v`` that is <= ``pref`` — the shared tile
-    picker (grids must divide the array dims exactly)."""
-    t = min(pref, v)
-    while v % t:
-        t -= 1
-    return max(t, 1)
+def pick_tile(v: int, pref: int, align: int) -> int:
+    """Largest divisor of ``v`` that is <= ``pref`` and a multiple of
+    ``align``; ``v`` itself when ``v <= pref`` or no such divisor exists
+    (a block as long as the whole dim is always legal on TPU, which
+    otherwise wants 8-row and 128-lane multiples)."""
+    if v <= pref:
+        return v
+    t = pref - pref % align
+    while t >= align:
+        if v % t == 0:
+            return t
+        t -= align
+    return v
 
 
 # ---------------------------------------------------------------------------
@@ -178,23 +140,21 @@ def resolve(
     if backend == "auto":
         backend = "pallas"
     if backend == "ref":
-        choice = KernelChoice("ref", False)
-    else:
-        if interpret is None:
-            interpret = interpret_default(platform)
-        choice = KernelChoice("pallas", bool(interpret))
-    _record_dispatch(op, choice)
-    return choice
+        return KernelChoice("ref", False)
+    if interpret is None:
+        interpret = interpret_default(platform)
+    return KernelChoice("pallas", bool(interpret))
 
 
-def _record_dispatch(op: str, choice: KernelChoice) -> None:
-    """Observability tap on backend selection: a labeled counter (always)
-    plus a trace instant (when tracing is enabled)."""
+def record(op: str, choice: KernelChoice) -> None:
+    """Book the backend a call site runs, once per trace and after any
+    fallback: a labeled counter (always) plus a trace instant (when
+    tracing is enabled)."""
     from ..obs.registry import REGISTRY
     from ..obs.trace import get_tracer
 
     REGISTRY.counter(
-        "kernel_dispatch_total", "kernel backend selections by resolve()",
+        "kernel_dispatch_total", "kernel backend traced per call site",
         op=op, backend=choice.backend, interpret=choice.interpret,
     ).inc()
     tr = get_tracer()

@@ -1,7 +1,7 @@
 """Dispatching wrapper for the flash-attention kernel.
 
 NOTE: this kernel keeps the full K/V for one kv-head resident in VMEM
-(block = (1, S, 1, hd)) — correct and MXU-aligned for S*hd*4B within the
+(block = (1, 1, S, hd)) — correct and MXU-aligned for S*hd*4B within the
 ~16 MB VMEM budget (S <= ~8k at hd=128, <= ~16k at hd=64). Longer
 sequences use the pure-JAX blockwise path in models/attention.py, which
 streams KV from HBM; a production double-buffered DMA variant is the
@@ -14,8 +14,9 @@ import functools
 from typing import Optional
 
 import jax
+import jax.numpy as jnp
 
-from ..dispatch import resolve
+from ..dispatch import KernelChoice, record, resolve
 from .kernel import flash_attention_fwd
 from .ref import attention_ref
 
@@ -33,16 +34,35 @@ def supported(q_shape, k_shape, interpret: bool) -> bool:
     return 2 * S * hd * 4 <= _VMEM_KV_BUDGET
 
 
+def _tile(n: int, pref: int):
+    """(tile, padded length): the tile is a multiple of 8 and at most
+    ``pref``; the length is padded up to a whole number of tiles."""
+    n8 = -(-n // 8) * 8
+    t = min(pref, n8)
+    return t, -(-n8 // t) * t
+
+
 @functools.partial(jax.jit, static_argnames=("softcap", "window", "bq", "bk",
                                              "interpret"))
-def _flash_pallas(q, k, v, softcap, window, bq, bk, interpret):
+def flash_pallas(q, k, v, softcap: Optional[float] = None,
+                 window: Optional[int] = None, bq: int = 256, bk: int = 256,
+                 interpret: bool = False):
+    """The kernel at any T <= S: q and k start at the same position
+    (self-attention prefill). T and S are padded to whole tiles; the
+    padded keys sit past every real query, so the causal mask hides
+    them, and the padded query rows are sliced off."""
     T, S = q.shape[1], k.shape[1]
-    while T % bq:
-        bq //= 2
-    while S % bk:
-        bk //= 2
-    return flash_attention_fwd(q, k, v, softcap=softcap, window=window,
-                               bq=max(bq, 1), bk=max(bk, 1), interpret=interpret)
+    if T > S or bq % 8 or bk % 8:
+        raise ValueError(f"flash_pallas needs T <= S and tiles that are "
+                         f"multiples of 8: T={T} S={S} bq={bq} bk={bk}")
+    bq, Tp = _tile(T, bq)
+    bk, Sp = _tile(S, bk)
+    q = jnp.pad(q, ((0, 0), (0, Tp - T), (0, 0), (0, 0), (0, 0)))
+    k = jnp.pad(k, ((0, 0), (0, Sp - S), (0, 0), (0, 0)))
+    v = jnp.pad(v, ((0, 0), (0, Sp - S), (0, 0), (0, 0)))
+    out = flash_attention_fwd(q, k, v, softcap=softcap, window=window,
+                              bq=bq, bk=bk, interpret=interpret)
+    return out[:, :T]
 
 
 def flash(q, k, v, *, softcap: Optional[float] = None,
@@ -52,6 +72,9 @@ def flash(q, k, v, *, softcap: Optional[float] = None,
     """Causal GQA attention. q (B,T,Hkv,G,hd); k/v (B,S,Hkv,hd)."""
     choice = resolve("flash_attn", backend or ("ref" if use_ref else "pallas"),
                      interpret=interpret)
-    if not choice.use_pallas or not supported(q.shape, k.shape, choice.interpret):
+    if choice.use_pallas and not supported(q.shape, k.shape, choice.interpret):
+        choice = KernelChoice("ref", False)
+    record("flash_attn", choice)
+    if not choice.use_pallas:
         return attention_ref(q, k, v, softcap=softcap, window=window)
-    return _flash_pallas(q, k, v, softcap, window, bq, bk, choice.interpret)
+    return flash_pallas(q, k, v, softcap, window, bq, bk, choice.interpret)

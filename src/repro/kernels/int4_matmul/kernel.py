@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..dispatch import compiler_params
-
 
 def _kernel(x_ref, packed_ref, scale_ref, zero_ref, o_ref, acc_ref, *,
             group: int, n_k: int, out_dtype):
@@ -88,8 +86,7 @@ def int4_matmul(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        **compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, packed, scale, zero)

@@ -7,7 +7,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from ..dispatch import pick_tile, resolve
+from ..dispatch import pick_tile, record, resolve
 from .kernel import int4_matmul as _kernel_call
 from .ref import int4_matmul_ref
 
@@ -56,19 +56,20 @@ def int4_matmul(x, packed, scale, zero, *, group: int = 64,
     group = int(group)  # static jit arg; reject stray 0-d arrays
     choice = resolve("int4_matmul", backend or ("ref" if use_ref else "pallas"),
                      interpret=interpret)
+    record("int4_matmul", choice)
     if not choice.use_pallas:
         out = int4_matmul_ref(x2, packed, scale, zero, group)
         return out.reshape(*lead, -1)
     M = x2.shape[0]
     N = packed.shape[1]
     if bm is None:
-        bm = pick_tile(max(M, 1), 128)
+        bm = pick_tile(max(M, 1), 128, 8)
     if bn is None:
-        bn = pick_tile(N, 128)
+        bn = pick_tile(N, 128, 128)
     if bk is None:
         # bk must cover whole (pairs of) groups: step in 2*group units
         step = 2 * group
-        bk = step * pick_tile(K // step, max(512 // step, 1)) if K % step == 0 else K
+        bk = step * pick_tile(K // step, max(512 // step, 1), 1) if K % step == 0 else K
     out = _int4_pallas(x2, packed, scale, zero, group, bm, bn, bk,
                        choice.interpret)
     return out.reshape(*lead, -1)

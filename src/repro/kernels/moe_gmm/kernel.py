@@ -15,8 +15,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..dispatch import compiler_params
-
 
 def _kernel(a_ref, b_ref, o_ref, acc_ref, *, n_k: int, out_dtype):
     @pl.when(pl.program_id(3) == 0)
@@ -74,26 +72,30 @@ def gmm(
     """Grouped matmul. With ``group_sizes``, rows >= group_sizes[e] of
     ``a[e]`` MUST be zero (slot-dispatch buffers are zero-padded); the
     kernel then skips every M-tile past the group's row count — empty
-    cache slots cost no MXU work."""
+    cache slots cost no MXU work.
+
+    ``bm`` must be a multiple of 8 or cover the whole of M; M is padded
+    to a whole number of ``bm`` tiles and the output sliced back."""
     E, M, K = a.shape
     _, _, N = b.shape
     assert b.shape == (E, K, N)
     bm = min(bm, M)
     bn = min(bn, N)
     bk = min(bk, K)
+    if (bm % 8 and bm != M) or N % bn or K % bk:
+        raise ValueError(f"unaligned gmm tiles: M={M} bm={bm} N={N} bn={bn} "
+                         f"K={K} bk={bk}")
     # pad M to a tile multiple (caps are often ragged)
     padm = (-M) % bm
     if padm:
         a = jnp.pad(a, ((0, 0), (0, padm), (0, 0)))
         M = M + padm
-    assert N % bn == 0 and K % bk == 0, (N, K, bn, bk)
     n_k = K // bk
     grid = (E, M // bm, N // bn, n_k)
     out_dtype = a.dtype
     out_shape = jax.ShapeDtypeStruct((E, M, N), out_dtype)
-    params = compiler_params(
-        dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
-    )
+    params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))
     if group_sizes is None:
         kernel = functools.partial(_kernel, n_k=n_k, out_dtype=out_dtype)
         out = pl.pallas_call(
@@ -106,8 +108,9 @@ def gmm(
             out_specs=pl.BlockSpec((1, bm, bn), lambda e, i, j, k: (e, i, j)),
             out_shape=out_shape,
             scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-            **params,
+            compiler_params=params,
             interpret=interpret,
+            name="moe_gmm",
         )(a, b)
     else:
         kernel = functools.partial(_kernel_ragged, n_k=n_k, bm=bm,
@@ -126,7 +129,8 @@ def gmm(
             kernel,
             grid_spec=grid_spec,
             out_shape=out_shape,
-            **params,
+            compiler_params=params,
             interpret=interpret,
+            name="moe_gmm_ragged",
         )(jnp.asarray(group_sizes, jnp.int32), a, b)
     return out[:, : M - padm] if padm else out

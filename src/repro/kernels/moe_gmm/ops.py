@@ -8,30 +8,24 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from ..dispatch import pick_tile, resolve
+from ..dispatch import KernelChoice, pick_tile, record, resolve
 from .kernel import gmm as _gmm_kernel
 from .ref import gmm_ref
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def _gmm_pallas(a, b, interpret: bool):
+def gmm_pallas(a, b, group_sizes=None, interpret: bool = False):
+    """The kernel with TPU-legal tiles: rows are the whole of M up to
+    128, else 128-row tiles over M padded by the kernel (the token
+    count M is arbitrary, so a divisor of it may be unaligned); N and K
+    take 128-lane multiples that divide them."""
     E, M, K = a.shape
     N = b.shape[-1]
-    bm = pick_tile(max(M, 1), 128)
-    bn = pick_tile(N, 128)
-    bk = pick_tile(K, 512)
-    return _gmm_kernel(a, b, bm=bm, bn=bn, bk=bk, interpret=interpret)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _gmm_pallas_ragged(a, b, sizes, interpret: bool):
-    E, M, K = a.shape
-    N = b.shape[-1]
-    bm = pick_tile(max(M, 1), 128)
-    bn = pick_tile(N, 128)
-    bk = pick_tile(K, 512)
-    return _gmm_kernel(a, b, bm=bm, bn=bn, bk=bk, interpret=interpret,
-                       group_sizes=sizes)
+    if group_sizes is not None:
+        group_sizes = jnp.asarray(group_sizes, jnp.int32)
+    return _gmm_kernel(a, b, bm=min(M, 128), bn=pick_tile(N, 128, 128),
+                       bk=pick_tile(K, 512, 128), interpret=interpret,
+                       group_sizes=group_sizes)
 
 
 def gmm(a, b, interpret: Optional[bool] = None, use_ref: bool = False,
@@ -49,9 +43,9 @@ def gmm(a, b, interpret: Optional[bool] = None, use_ref: bool = False,
     N = b.shape[-1]
     choice = resolve("moe_gmm", backend or ("ref" if use_ref else "pallas"),
                      interpret=interpret)
-    if not choice.use_pallas or M * N * K == 0:
+    if M * N * K == 0:
+        choice = KernelChoice("ref", False)
+    record("moe_gmm", choice)
+    if not choice.use_pallas:
         return gmm_ref(a, b)
-    if group_sizes is None:
-        return _gmm_pallas(a, b, choice.interpret)
-    return _gmm_pallas_ragged(a, b, jnp.asarray(group_sizes, jnp.int32),
-                              choice.interpret)
+    return gmm_pallas(a, b, group_sizes, choice.interpret)

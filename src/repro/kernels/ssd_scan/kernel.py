@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..dispatch import compiler_params
-
 
 def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, init_ref, y_ref, fin_ref,
             state_ref, *, n_chunks: int, out_dtype):
@@ -116,9 +114,8 @@ def ssd_scan(
             jax.ShapeDtypeStruct((B, H, P, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        **compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, dt, A, Bm, Cm, init.astype(jnp.float32))
     return y, fin

@@ -7,7 +7,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from ..dispatch import resolve
+from ..dispatch import record, resolve
 from .kernel import ssd_scan as _ssd_kernel
 from .ref import ssd_scan_ref
 
@@ -30,8 +30,16 @@ def ssd(x, dt, A, Bm, Cm, *, init=None, chunk: int = 128,
     -> (y, final_state)."""
     choice = resolve("ssd_scan", backend or ("ref" if use_ref else "pallas"),
                      interpret=interpret)
+    record("ssd_scan", choice)
     if not choice.use_pallas:
         return ssd_scan_ref(x, dt, A, Bm, Cm, init)
+    return ssd_pallas(x, dt, A, Bm, Cm, init=init, chunk=chunk,
+                      interpret=choice.interpret)
+
+
+def ssd_pallas(x, dt, A, Bm, Cm, *, init=None, chunk: int = 128,
+               interpret: bool = False):
+    """The kernel, shapes as :func:`ssd`."""
     if Bm.ndim == 3:  # shared across heads == one group
         Bm = Bm[:, :, None]
         Cm = Cm[:, :, None]
@@ -39,4 +47,4 @@ def ssd(x, dt, A, Bm, Cm, *, init=None, chunk: int = 128,
     N = Bm.shape[-1]
     if init is None:
         init = jnp.zeros((B, H, P, N), jnp.float32)
-    return _ssd_pallas(x, dt, A, Bm, Cm, init, chunk, choice.interpret)
+    return _ssd_pallas(x, dt, A, Bm, Cm, init, chunk, interpret)

@@ -16,6 +16,12 @@ in-flight, checkpoint, exit 0) and still exits 0 as long as every
 request is finished or checkpointed.
 
 Exit status: 0 iff no request is unaccounted (finished nor journaled).
+
+The launcher itself never imports JAX: each worker is its own JAX
+process (one per accelerator, or CPU processes), and a parent that had
+touched JAX would hold the chip the workers need. Offloaded workers
+score their own requests' expert profiles, since they build the model
+anyway.
 """
 from __future__ import annotations
 
@@ -28,7 +34,7 @@ import sys
 from ..configs import get_config
 from ..data.synthetic import ClusterLM, SyntheticConfig
 from ..fleet import FleetConfig, FleetSupervisor, parse_worker_fault_schedule
-from ..serving import TrafficConfig, prefill_expert_scores, synthesize_workload
+from ..serving.queue import TrafficConfig, synthesize_workload
 
 
 def build_workload(args, cfg):
@@ -95,11 +101,6 @@ def main(argv=None) -> int:
     requests = build_workload(args, cfg)
     if args.offloaded:
         assert cfg.has_router, "offloaded fleet needs a MoE arch"
-        import jax
-        import jax.numpy as jnp
-        from ..models.model import init_params
-        params = init_params(jax.random.key(0), cfg, jnp.float32)
-        prefill_expert_scores(cfg, params, requests)  # ride in the trace
 
     fcfg = FleetConfig(
         n_workers=args.workers, arch=args.arch,

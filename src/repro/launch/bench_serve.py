@@ -12,6 +12,7 @@ Synthesizes a Poisson/bursty workload over the ClusterLM prompt
 distribution, serves it through the chosen scheduler, and prints the
 ServerMetrics summary (throughput, latency percentiles, queue depth,
 slot occupancy, and — offloaded — transfers + cache hit rate).
+``main(argv)`` returns ``(results, metrics)`` for in-process callers.
 """
 from __future__ import annotations
 
@@ -27,6 +28,7 @@ from ..configs import get_config
 from ..data.synthetic import ClusterLM, SyntheticConfig
 from ..faults import InjectedCrash, get_fault_plan, install_fault_plan
 from ..models.model import init_params
+from ..models.runtime import Runtime
 from ..obs import REGISTRY, enable_tracing, get_tracer, reconcile
 from ..serving import (
     ContinuousBatchingServer,
@@ -38,9 +40,13 @@ from ..serving import (
     synthesize_workload,
 )
 from ..training.checkpoint import load_checkpoint
+from .compile_cache import use_compile_cache
 
 
-def main():
+def main(argv=None):
+    """Serve one synthesized workload; returns ``(results, metrics)``
+    (``(None, None)`` after an injected crash)."""
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="olmoe-mini")
     ap.add_argument("--ckpt", default=None)
@@ -63,9 +69,16 @@ def main():
                     choices=["poisson", "bursty", "all_at_once"])
     ap.add_argument("--rate", type=float, default=4.0, help="requests / second")
     ap.add_argument("--prompt-len", type=int, default=24)
+    ap.add_argument("--min-prompt-len", type=int, default=None,
+                    help="shortest prompt (default: half of --prompt-len)")
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--kernel-backend", default="ref",
+                    help="kernel dispatch spec for the whole served path: "
+                         "ref | pallas | auto, optionally per op "
+                         "('auto,flash_attn=ref'); Pallas compiles on a "
+                         "TPU and runs interpreted elsewhere")
     ap.add_argument("--faults", default=None, metavar="SPEC",
                     help="install a deterministic fault plan, e.g. "
                          "'fail=0.1,spike=0.05:2e-3,storm=0.02:0.5,seed=7' "
@@ -111,7 +124,7 @@ def main():
                     help="write per-request tokens + summary JSON (use to "
                          "diff a crashed+resumed run against an "
                          "uninterrupted one)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     if args.trace:
         enable_tracing()
@@ -152,7 +165,8 @@ def main():
                                        seed=args.seed + 3))
         tcfg = TrafficConfig(
             n_requests=args.n_requests, arrival=args.arrival, rate=args.rate,
-            prompt_len=(max(args.prompt_len // 2, 1), args.prompt_len),
+            prompt_len=(args.min_prompt_len or max(args.prompt_len // 2, 1),
+                        args.prompt_len),
             max_new_tokens=(max(args.max_new // 2, 1), args.max_new),
             temperature=args.temperature, seed=args.seed,
             slo=args.slo, quality=args.quality,
@@ -162,6 +176,7 @@ def main():
         get_fault_plan().compress_arrivals(requests)
         queue = RequestQueue(requests, max_pending=args.max_backlog)
 
+    rt = Runtime(zero_drop=True, kernel_backend=args.kernel_backend)
     if args.offloaded:
         assert cfg.has_router, "offloaded serving applies to MoE architectures"
         if args.temperature > 0:
@@ -169,7 +184,7 @@ def main():
                   "--temperature is ignored on this path")
         capacity = args.capacity or cfg.melinoe_cache_capacity()
         if state is None:
-            prefill_expert_scores(cfg, params, requests)  # oracle profiles
+            prefill_expert_scores(cfg, params, requests, rt=rt)  # oracle profiles
         kw = {"top_c": capacity} if args.scheduler == "expert-affinity" else {}
         srv = OffloadedWaveServer(
             cfg, params, capacity=capacity,
@@ -177,6 +192,7 @@ def main():
             overlap=args.overlap, engine_impl=args.engine_impl,
             little_experts=args.little, little_rank=args.little_rank,
             seed=state.seed if state else args.seed,
+            kernel_backend=args.kernel_backend,
         )
         if state is not None and state.engine is not None:
             srv.engine.metrics.load_state(state.engine["metrics"])
@@ -188,7 +204,7 @@ def main():
         srv = ContinuousBatchingServer(
             cfg, params, n_slots=args.slots,
             max_len=args.prompt_len + args.max_new + 1,
-            scheduler=get_scheduler(args.scheduler),
+            scheduler=get_scheduler(args.scheduler), rt=rt,
             seed=state.seed if state else args.seed,
         )
 
@@ -215,7 +231,7 @@ def main():
         print(f"CRASHED (injected): {e}")
         print(f"journal is recoverable at {jdir}" if jdir else
               "no journal configured; run is lost")
-        return
+        return None, None
     finally:
         if jr is not None:
             jr.close()
@@ -241,6 +257,7 @@ def main():
 
     if args.trace:
         _export_trace(args.trace, srv, mt, offloaded=args.offloaded)
+    return results, mt
 
 
 def _export_trace(outdir: str, srv, mt, *, offloaded: bool) -> None:

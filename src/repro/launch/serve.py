@@ -16,7 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..configs import get_config
-from ..core.offload_engine import HardwareProfile, OffloadedMoEEngine
+from ..core.offload_engine import OffloadedMoEEngine
 from ..core.predictor import (
     PromptEmbedder,
     init_predictor,
@@ -27,9 +27,11 @@ from ..data.synthetic import ClusterLM, SyntheticConfig
 from ..inference.engine import routing_trace
 from ..models.model import init_params
 from ..training.checkpoint import load_checkpoint
+from .compile_cache import use_compile_cache
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="olmoe-mini")
     ap.add_argument("--ckpt", default=None)
@@ -64,7 +66,7 @@ def main():
 
     engine = OffloadedMoEEngine(
         cfg, params, capacity=capacity, policy=args.policy,
-        quantized=args.quantized, hw=HardwareProfile(),
+        quantized=args.quantized,
     )
 
     if args.predictor:

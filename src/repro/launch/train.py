@@ -21,9 +21,11 @@ from ..models.runtime import Runtime
 from ..training.checkpoint import save_checkpoint
 from ..training.optim import OptConfig
 from ..training.trainer import melinoe_finetune, pretrain
+from .compile_cache import use_compile_cache
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="olmoe-mini")
     ap.add_argument("--steps", type=int, default=200)

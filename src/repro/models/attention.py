@@ -292,18 +292,22 @@ def attend_full(params, spec: AttnSpec, x, positions, window: Optional[int],
     q, k, v = _project_qkv(params, spec, x, positions)
     o = None
     if rt is not None:
-        choice = rt.kernel_choice("flash_attn")
-        if choice.use_pallas:
-            from ..kernels.flash_attn import ops as flash_ops
+        from ..kernels import dispatch
+        from ..kernels.flash_attn import ops as flash_ops
 
-            if flash_ops.supported(q.shape, k.shape, choice.interpret):
-                B, T, Hq, hd = q.shape
-                G = Hq // spec.n_kv_heads
-                qg = q.reshape(B, T, spec.n_kv_heads, G, hd)
-                o = flash_ops.flash(
-                    qg, k, v, softcap=spec.attn_softcap, window=window,
-                    backend="pallas", interpret=choice.interpret,
-                ).reshape(B, T, Hq, hd)
+        choice = rt.kernel_choice("flash_attn")
+        if choice.use_pallas and not flash_ops.supported(q.shape, k.shape,
+                                                         choice.interpret):
+            choice = dispatch.KernelChoice("ref", False)
+        dispatch.record("flash_attn", choice)
+        if choice.use_pallas:
+            B, T, Hq, hd = q.shape
+            G = Hq // spec.n_kv_heads
+            qg = q.reshape(B, T, spec.n_kv_heads, G, hd)
+            o = flash_ops.flash_pallas(
+                qg, k, v, softcap=spec.attn_softcap, window=window,
+                interpret=choice.interpret,
+            ).reshape(B, T, Hq, hd)
     if o is None:
         o = flash_attention(q, k, v, spec, window=window)
     out = o.reshape(*x.shape[:2], spec.q_dim) @ params["wo"]

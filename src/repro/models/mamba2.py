@@ -165,13 +165,18 @@ def apply_mamba_full(params, x_in, spec: SSMSpec, *, init_state: Optional[MambaS
     dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + params["dt_bias"][None, None])
     A = -jnp.exp(params["A_log"])
     ssm_init = init_state.ssm if init_state is not None else None
-    choice = rt.kernel_choice("ssd_scan") if rt is not None else None
+    choice = None
+    if rt is not None:
+        from ..kernels import dispatch
+
+        choice = rt.kernel_choice("ssd_scan")
+        dispatch.record("ssd_scan", choice)
     if choice is not None and choice.use_pallas:
         from ..kernels.ssd_scan import ops as ssd_ops
 
-        y, final = ssd_ops.ssd(
+        y, final = ssd_ops.ssd_pallas(
             xs, dt, A, Bm, Cm, init=ssm_init, chunk=spec.chunk,
-            backend="pallas", interpret=choice.interpret,
+            interpret=choice.interpret,
         )
         y = y.astype(jnp.float32)
     else:

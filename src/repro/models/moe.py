@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from ..configs.base import MoESpec
 from .common import dense_init, silu
@@ -154,11 +153,14 @@ def expert_ffn(params, buf, rt: Runtime, lora: Optional[dict] = None, lora_scale
     wg = _expert_weights(params, lora, lora_scale, "wg")
     wu = _expert_weights(params, lora, lora_scale, "wu")
     wd = _expert_weights(params, lora, lora_scale, "wd")
+    from ..kernels import dispatch
+
     choice = rt.kernel_choice("moe_gmm")
+    dispatch.record("moe_gmm", choice)
     if choice.use_pallas:
         from ..kernels.moe_gmm import ops as gmm_ops
 
-        gmm = partial(gmm_ops.gmm, backend="pallas", interpret=choice.interpret)
+        gmm = partial(gmm_ops.gmm_pallas, interpret=choice.interpret)
     else:
         gmm = lambda a, b: jnp.einsum("ecd,edf->ecf", a, b)
     h = silu(gmm(buf, wg)) * gmm(buf, wu)
@@ -240,12 +242,12 @@ def apply_moe_sharded(params, x2d, spec: MoESpec, rt: Runtime, lora=None,
         return combine_tokens(d, out)
 
     lora_specs = jax.tree.map(lambda _: ew_spec, lora)
-    y = shard_map(
+    y = jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(tok_spec, tok_spec, tok_spec, ew_spec, ew_spec, ew_spec, lora_specs),
         out_specs=tok_spec,
-        check_rep=False,
+        check_vma=False,
     )(x2d, gates, eids, params["wg"], params["wu"], params["wd"], lora)
     if spec.shared_d_ff:
         y = y + apply_mlp(params["shared"], x2d)

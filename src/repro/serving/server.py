@@ -478,7 +478,7 @@ class OffloadedWaveServer:
         scheduler: Optional[Scheduler] = None,
         wave_size: int = 4,
         quantized: bool = False,
-        hw: HardwareProfile = HardwareProfile(),
+        hw: Optional[HardwareProfile] = None,
         use_prefetch: bool = True,
         lora=None,
         lora_scale: float = 1.0,
@@ -491,12 +491,12 @@ class OffloadedWaveServer:
         pressure_frac: float = 0.75,
         max_backlog: Optional[int] = None,
         seed: int = 0,
+        kernel_backend: str = "ref",
     ):
         self.cfg = cfg
         self.seed = seed  # recorded in recovery checkpoints
         self.scheduler = scheduler or FCFSScheduler()
         self.wave_size = wave_size
-        self.hw = hw
         self.use_prefetch = use_prefetch
         self.overlap = overlap
         self.max_backlog = max_backlog
@@ -506,7 +506,9 @@ class OffloadedWaveServer:
             impl=engine_impl, little_experts=little_experts,
             little_rank=little_rank, little_quantized=little_quantized,
             fetch_policy=fetch_policy, pressure_frac=pressure_frac,
+            kernel_backend=kernel_backend,
         )
+        self.hw = self.engine.hw
 
     def run(self, queue: RequestQueue,
             metrics: Optional[ServerMetrics] = None,

@@ -96,14 +96,6 @@ def test_sharded_runtime_pins_ref_even_under_env(monkeypatch):
     assert rt.kernel_choice("moe_gmm").use_pallas  # env honoured unsharded
 
 
-def test_compiler_params_shim_matches_installed_jax():
-    kw = dispatch.compiler_params(dimension_semantics=("parallel", "arbitrary"))
-    # whatever the pinned JAX exposes, the shim must produce kwargs that
-    # pallas_call accepts (empty dict = no params supported)
-    assert isinstance(kw, dict)
-    assert set(kw) <= {"compiler_params"}
-
-
 def test_runtime_legacy_use_kernels_maps_to_auto():
     rt = Runtime(use_kernels=True)
     assert rt.kernel_backend == "auto"
@@ -143,6 +135,30 @@ def test_attention_prefill_parity():
     y_pal = attn_mod.attend_full(params, spec, x, pos, None, rt=RT_PALLAS)
     np.testing.assert_allclose(np.asarray(y_pal), np.asarray(y_ref),
                                atol=1e-5, rtol=1e-4)
+
+
+def test_flash_bailout_books_ref(monkeypatch):
+    """A shape the compiled kernel cannot take falls back to the
+    blockwise reference, and the dispatch counter says so: it books the
+    backend that ran, never the one first resolved."""
+    from repro.kernels.flash_attn import ops as flash_ops
+    from repro.obs.registry import REGISTRY, MetricsRegistry
+
+    monkeypatch.setattr(flash_ops, "supported", lambda *a: False)
+    spec = AttnSpec(n_heads=4, n_kv_heads=2, head_dim=16)
+    params = attn_mod.init_attn(jax.random.key(2), 32, spec, jnp.float32)
+    x = jax.random.normal(jax.random.key(3), (1, 8, 32))
+    pos = jnp.broadcast_to(jnp.arange(8), (1, 8))
+    before = REGISTRY.snapshot()
+    y = attn_mod.attend_full(params, spec, x, pos, None, rt=RT_PALLAS)
+    y_ref = attn_mod.attend_full(params, spec, x, pos, None, rt=RT_REF)
+    d = MetricsRegistry.diff(REGISTRY.snapshot(), before)
+    booked = {k: v for k, v in d.items()
+              if k.startswith("kernel_dispatch_total") and 'op="flash_attn"' in k
+              and v}
+    assert list(booked.values()) == [2.0], booked
+    assert all('backend="ref"' in k for k in booked), booked
+    np.testing.assert_array_equal(np.asarray(y), np.asarray(y_ref))
 
 
 @pytest.mark.parametrize("n_groups", [1, 2])

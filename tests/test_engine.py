@@ -101,3 +101,28 @@ def test_prefetch_counts_separately(setup):
     eng.prefetch(scores)
     assert eng.metrics.prefetch_transfers == cfg.n_moe_layers * 2
     assert eng.metrics.transfers == 0
+
+
+class _FakeDevice:
+    def __init__(self, platform, kind):
+        self.platform, self.device_kind = platform, kind
+
+
+@pytest.mark.parametrize("platform,kind,name", [
+    ("tpu", "TPU v5 lite", "tpu-v5e"),
+    ("cpu", "cpu", "tpu-v5e"),  # off-chip: the documented target profile
+])
+def test_hardware_profile_from_device_kind(platform, kind, name):
+    from repro.core.offload_engine import TPU_PEAKS, hardware_profile
+
+    hw = hardware_profile(_FakeDevice(platform, kind))
+    assert hw.name == name
+    assert (hw.peak_flops, hw.hbm_bw) == (197e12, 819e9)
+    assert set(TPU_PEAKS) == {"TPU v5 lite"}
+
+
+def test_hardware_profile_unknown_tpu_kind_raises():
+    from repro.core.offload_engine import hardware_profile
+
+    with pytest.raises(ValueError, match="TPU v9"):
+        hardware_profile(_FakeDevice("tpu", "TPU v9"))

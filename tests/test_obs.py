@@ -235,12 +235,14 @@ def test_registry_type_conflict():
 
 
 def test_kernel_dispatch_counts():
-    from repro.kernels.dispatch import resolve
+    from repro.kernels.moe_gmm import gmm
     from repro.obs.registry import REGISTRY
 
+    a = jnp.ones((2, 8, 128), jnp.float32)
+    b = jnp.ones((2, 128, 128), jnp.float32)
     before = REGISTRY.snapshot()
-    resolve("moe_gmm", "auto")
-    resolve("moe_gmm", "ref")
+    gmm(a, b, backend="auto")
+    gmm(a, b, backend="ref")
     d = MetricsRegistry.diff(REGISTRY.snapshot(), before)
     inc = {k: v for k, v in d.items()
            if k.startswith("kernel_dispatch_total") and v}
