@@ -2,9 +2,11 @@
 Eq.-3 model-vs-measurement reconciliation.
 
 Layers:
-  trace.py      — nested spans + instant events (perf_counter), no-op
+  trace.py      — nested spans + instant events (perf_counter, plus the
+                  start on the profiler's clock as t0_ns), no-op
                   singleton when disabled, Chrome-trace/JSONL exporters,
-                  optional jax.profiler.TraceAnnotation pass-through
+                  optional jax.profiler.TraceAnnotation pass-through,
+                  JAX compiles charged to the innermost open span
   registry.py   — labeled counters/gauges/histograms, snapshot/diff,
                   JSON + Prometheus text export
   reconcile.py  — measured per-layer fetch/compute/overlap vs the

@@ -25,6 +25,16 @@ Design constraints, in order:
 Optional ``jax.profiler.TraceAnnotation`` pass-through: when a tracer is
 created with ``jax_annotations=True``, every span also opens an XLA
 profiler annotation, so spans line up inside ``jax.profiler`` captures.
+Every record also carries ``t0_ns``, its start on the profiler's clock
+(``time.time_ns()``, the ``CLOCK_REALTIME`` the profiler stamps host
+events with), so a span can be placed in a device trace without an
+anchor; durations stay on ``perf_counter``.
+
+JAX compile accounting: while tracing is enabled, every JAX trace,
+lowering, backend compile and persistent-cache read is charged to the
+innermost open span of the thread that compiled (span args
+``jax_trace_s``, ``jax_lower_s``, ``jax_compile_s``, ``jax_cache_s``,
+``jax_compiles``) and counted in ``jax_compiles_total{span=...}``.
 """
 from __future__ import annotations
 
@@ -34,6 +44,8 @@ import threading
 import time
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional
+
+from .registry import REGISTRY
 
 try:  # pragma: no cover - present in every supported JAX
     from jax.profiler import TraceAnnotation as _JaxAnnotation
@@ -48,7 +60,8 @@ except Exception:  # pragma: no cover
 
 @dataclass
 class SpanRecord:
-    """One completed span. Times are ``perf_counter`` seconds."""
+    """One completed span. ``t0`` and ``dur`` are ``perf_counter``
+    seconds; ``t0_ns`` is the same start on the profiler's clock."""
 
     name: str
     t0: float
@@ -56,6 +69,7 @@ class SpanRecord:
     tid: int
     depth: int
     args: Dict[str, Any] = field(default_factory=dict)
+    t0_ns: int = 0
 
     @property
     def t1(self) -> float:
@@ -70,6 +84,7 @@ class InstantRecord:
     t0: float
     tid: int
     args: Dict[str, Any] = field(default_factory=dict)
+    t0_ns: int = 0
 
 
 def _jsonable(v: Any) -> Any:
@@ -97,13 +112,14 @@ def _jsonable(v: Any) -> Any:
 class _SpanCtx:
     """Context manager for one live span on the real tracer."""
 
-    __slots__ = ("_tr", "name", "args", "t0", "dur", "_depth", "_jax")
+    __slots__ = ("_tr", "name", "args", "t0", "t0_ns", "dur", "_depth", "_jax")
 
     def __init__(self, tr: "Tracer", name: str, args: Dict[str, Any]):
         self._tr = tr
         self.name = name
         self.args = args
         self.t0 = 0.0
+        self.t0_ns = 0
         self.dur = 0.0
         self._depth = 0
         self._jax = None
@@ -116,6 +132,7 @@ class _SpanCtx:
         if tr.jax_annotations and _JaxAnnotation is not None:
             self._jax = _JaxAnnotation(self.name)
             self._jax.__enter__()
+        self.t0_ns = time.time_ns()
         self.t0 = time.perf_counter()
         return self
 
@@ -129,7 +146,8 @@ class _SpanCtx:
             stack.pop()
         tr._append_span(
             SpanRecord(self.name, self.t0, self.dur,
-                       threading.get_ident(), self._depth, self.args))
+                       threading.get_ident(), self._depth, self.args,
+                       self.t0_ns))
 
 
 class _NullCtx:
@@ -209,14 +227,39 @@ class Tracer:
         return _SpanCtx(self, name, args)
 
     def instant(self, name: str, **args) -> None:
+        t0_ns = time.time_ns()
         rec = InstantRecord(name, time.perf_counter(),
-                            threading.get_ident(), args)
+                            threading.get_ident(), args, t0_ns)
         with self._lock:
             self._instants.append(rec)
             if len(self._instants) > self.max_records:
                 drop = len(self._instants) // 2
                 del self._instants[:drop]
                 self.dropped += drop
+
+    def charge_jax(self, key: str, secs: float) -> None:
+        """Add one JAX compile event (span-arg name ``key``) to the
+        innermost open span of this thread. A backend compile event's
+        time includes the persistent-cache read that fires inside it;
+        that read is charged as ``jax_cache_s`` alone, so the keys add
+        up to the time JAX spent. One backend compile event is one
+        executable obtained (compiled, or read from the cache)."""
+        stack = self._stack()
+        top = stack[-1] if stack else None
+        local = self._local
+        if key == "jax_cache_s":
+            local.cache_s = getattr(local, "cache_s", 0.0) + secs
+        elif key == "jax_compile_s":
+            secs = max(secs - getattr(local, "cache_s", 0.0), 0.0)
+            local.cache_s = 0.0
+            REGISTRY.counter(
+                "jax_compiles_total",
+                "JAX executables obtained, by innermost open span",
+                span=top.name if top is not None else "none").inc()
+            if top is not None:
+                top.args["jax_compiles"] = top.args.get("jax_compiles", 0) + 1
+        if top is not None:
+            top.args[key] = top.args.get(key, 0.0) + secs
 
     def _append_span(self, rec: SpanRecord) -> None:
         with self._lock:
@@ -259,13 +302,14 @@ class Tracer:
             for s in self.spans():
                 f.write(json.dumps({
                     "kind": "span", "name": s.name, "t0": s.t0,
+                    "t0_ns": s.t0_ns,
                     "dur": s.dur, "tid": s.tid, "depth": s.depth,
                     "args": {k: _jsonable(v) for k, v in s.args.items()},
                 }) + "\n")
             for i in self.instants():
                 f.write(json.dumps({
                     "kind": "instant", "name": i.name, "t0": i.t0,
-                    "tid": i.tid,
+                    "t0_ns": i.t0_ns, "tid": i.tid,
                     "args": {k: _jsonable(v) for k, v in i.args.items()},
                 }) + "\n")
 
@@ -330,10 +374,37 @@ def get_tracer():
     return _tracer
 
 
+# jax.monitoring duration events -> span-arg names (Tracer.charge_jax)
+JAX_COMPILE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax_trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax_lower_s",
+    "/jax/core/compile/backend_compile_duration": "jax_compile_s",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "jax_cache_s",
+}
+_jax_listening = False
+
+
+def _on_jax_duration(event: str, duration: float, **_) -> None:
+    """jax.monitoring listener: fires on compiles only, never per op;
+    with tracing off it costs one attribute check."""
+    tr = _tracer
+    if not tr.enabled:
+        return
+    key = JAX_COMPILE_EVENTS.get(event)
+    if key is not None:
+        tr.charge_jax(key, duration)
+
+
 def enable_tracing(*, jax_annotations: bool = False,
                    max_records: int = 1_000_000) -> Tracer:
-    """Install (and return) a fresh recording tracer as the global."""
-    global _tracer
+    """Install (and return) a fresh recording tracer as the global.
+    The first call registers the JAX compile listener."""
+    global _tracer, _jax_listening
+    if not _jax_listening:
+        import jax.monitoring
+
+        jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+        _jax_listening = True
     _tracer = Tracer(jax_annotations=jax_annotations,
                      max_records=max_records)
     return _tracer

@@ -180,19 +180,26 @@ class ContinuousBatchingServer:
         (its journaled watermark); greedy decode depends only on the
         token prefix, so the continuation is token-identical to the
         uninterrupted run."""
+        tr = get_tracer()
         inp = (req.prompt if req.resumed is None else
                np.concatenate([req.prompt, req.resumed]).astype(np.int32))
-        logits, pre_cache = prefill(
-            self.params, self.cfg, jnp.asarray(inp, jnp.int32)[None],
-            self.rt, n_slots=self.max_len, window_override=self.window_override,
-            lora=self.lora, lora_scale=self.lora_scale,
-        )
-        self.cache = self._insert_jit(self.cache, pre_cache, slot)
+        # host tracing, lowering and eager dispatch of the prefill
+        with tr.span("serve.prefill.forward"):
+            logits, pre_cache = prefill(
+                self.params, self.cfg, jnp.asarray(inp, jnp.int32)[None],
+                self.rt, n_slots=self.max_len,
+                window_override=self.window_override,
+                lora=self.lora, lora_scale=self.lora_scale,
+            )
+        with tr.span("serve.prefill.insert"):
+            self.cache = self._insert_jit(self.cache, pre_cache, slot)
         state.occupy(slot, req, now)
         mt.prefill_tokens += len(inp)
         # first generated token comes from the prefill logits (greedy, to
-        # match ServingEngine.generate_batch semantics)
-        tok = int(np.asarray(greedy(logits))[0, 0])
+        # match ServingEngine.generate_batch semantics); the host waits
+        # here for the device
+        with tr.span("serve.prefill.first_token"):
+            tok = int(np.asarray(greedy(logits))[0, 0])
         cur[slot, 0] = tok
         mt.generated_tokens += 1
         return state.append_token(slot, tok)
@@ -289,9 +296,6 @@ class ContinuousBatchingServer:
                     order = self.scheduler.order(ready, hot=state.active_requests())
                     for slot, req in zip(free, order):
                         queue.admit(req)
-                        if tr.enabled:
-                            tr.instant("serve.queue_wait", rid=req.rid,
-                                       wait_s=now - req.arrival_time)
                         # prefill is service time: the clock_span both
                         # advances the serving clock and (when tracing)
                         # records the same interval as a span
@@ -328,25 +332,30 @@ class ContinuousBatchingServer:
             # -- one fused decode step over the whole slot pool ---------
             with clock_span("serve.decode_step", active=len(active),
                             slots=self.n_slots) as cs:
-                logits, self.cache, _ = self._decode_jit(
-                    self.params, jnp.asarray(cur), self.cache
-                )
-                temps = np.zeros(self.n_slots, np.float32)
-                # filler (rid, step) for free/greedy rows: any non-negative
-                # value works, the draw is discarded by the temperature mask
-                rids = np.arange(self.n_slots, dtype=np.int32) + (2**31 - 1 - self.n_slots)
-                steps = np.zeros(self.n_slots, np.int32)
-                for s in active:
-                    slot = state.slots[s]
-                    temps[s] = slot.request.temperature
-                    rids[s] = slot.request.rid
-                    steps[s] = len(slot.generated)
-                if np.any(temps > 0):
-                    toks = self._sample_jit(logits, jnp.asarray(rids),
-                                            jnp.asarray(steps), jnp.asarray(temps))
-                else:
-                    toks = greedy(logits)
-                toks_np = np.asarray(toks)
+                with tr.span("serve.decode_step.dispatch"):
+                    logits, self.cache, _ = self._decode_jit(
+                        self.params, jnp.asarray(cur), self.cache
+                    )
+                    temps = np.zeros(self.n_slots, np.float32)
+                    # filler (rid, step) for free/greedy rows: any
+                    # non-negative value works, the draw is discarded by
+                    # the temperature mask
+                    rids = (np.arange(self.n_slots, dtype=np.int32)
+                            + (2**31 - 1 - self.n_slots))
+                    steps = np.zeros(self.n_slots, np.int32)
+                    for s in active:
+                        slot = state.slots[s]
+                        temps[s] = slot.request.temperature
+                        rids[s] = slot.request.rid
+                        steps[s] = len(slot.generated)
+                    if np.any(temps > 0):
+                        toks = self._sample_jit(logits, jnp.asarray(rids),
+                                                jnp.asarray(steps),
+                                                jnp.asarray(temps))
+                    else:
+                        toks = greedy(logits)
+                with tr.span("serve.decode_step.sync"):
+                    toks_np = np.asarray(toks)
             # charge the step (plus any injected scheduler hiccup) before
             # retiring
             now += cs.dur + plan.step_delay()
@@ -607,9 +616,6 @@ class OffloadedWaveServer:
                 queue.admit(req)
                 if jr is not None:
                     jr.admit(req.rid, now)
-                if tr.enabled:
-                    tr.instant("serve.queue_wait", rid=req.rid,
-                               wait_s=now - req.arrival_time)
                 start = now
                 before_s = eng.metrics.modeled_time(self.hw)
                 step0 = len(eng.metrics.step_flops)

@@ -177,6 +177,87 @@ def test_tracer_thread_safety():
     assert all(s.depth == 0 for s in spans)  # stacks are per-thread
 
 
+def test_t0_ns_is_present_and_ordered_like_t0(tmp_path):
+    tr = Tracer()
+    with tr.span("outer"):
+        for i in range(3):
+            time.sleep(0.002)
+            with tr.span("inner", i=i):
+                pass
+    tr.instant("mark")
+    spans = sorted(tr.spans(), key=lambda s: s.t0)
+    assert all(s.t0_ns > 0 for s in spans)
+    assert [s.t0_ns for s in spans] == sorted(s.t0_ns for s in spans)
+    # both clocks see the same stretch between the first and last start
+    wall = (spans[-1].t0_ns - spans[0].t0_ns) * 1e-9
+    assert wall == pytest.approx(spans[-1].t0 - spans[0].t0, abs=2e-3)
+    (mark,) = tr.instants()
+    assert mark.t0_ns >= spans[-1].t0_ns
+    tr.export_jsonl(tmp_path / "t.jsonl")
+    rows = [json.loads(l) for l in (tmp_path / "t.jsonl").read_text().splitlines()]
+    assert sorted(r["t0_ns"] for r in rows) == sorted(
+        [s.t0_ns for s in spans] + [mark.t0_ns])
+
+
+def _fresh_jit():
+    """A function JAX has never traced, so calling it compiles."""
+    k = time.perf_counter_ns() % 1000 + 2
+    return jax.jit(lambda x: x * k + 1), jnp.ones((k,), jnp.float32)
+
+
+def _compiles(span):
+    from repro.obs import REGISTRY
+
+    return REGISTRY.counter("jax_compiles_total", span=span).value
+
+
+def test_jit_compile_is_charged_to_the_innermost_span():
+    tr = enable_tracing()
+    before = _compiles("inner")
+    with tr.span("outer"):
+        with tr.span("inner"):
+            f, x = _fresh_jit()
+            f(x).block_until_ready()
+    inner, outer = tr.spans()
+    for key in ("jax_trace_s", "jax_lower_s", "jax_compile_s"):
+        assert inner.args[key] > 0, key
+    assert inner.args["jax_compiles"] >= 1
+    assert not any(k.startswith("jax_") for k in outer.args)
+    assert _compiles("inner") - before == inner.args["jax_compiles"]
+
+
+def test_cache_read_is_split_from_the_backend_compile():
+    """A persistent-cache read fires inside the backend compile event:
+    it is charged as jax_cache_s alone, and the compile keeps the rest."""
+    tr = enable_tracing()
+    none0 = _compiles("none")
+    with tr.span("s"):
+        tr.charge_jax("jax_cache_s", 0.2)
+        tr.charge_jax("jax_compile_s", 0.5)
+    (s,) = tr.spans()
+    assert s.args["jax_cache_s"] == pytest.approx(0.2)
+    assert s.args["jax_compile_s"] == pytest.approx(0.3)
+    assert s.args["jax_compiles"] == 1
+    tr.charge_jax("jax_compile_s", 0.1)  # no span open
+    assert _compiles("none") - none0 == 1
+
+
+def test_nothing_is_charged_or_stored_with_tracing_off():
+    from repro.obs import REGISTRY
+
+    enable_tracing()  # registers the listener
+    disable_tracing()
+    snap = {k: v for k, v in REGISTRY.snapshot().items()
+            if k.startswith("jax_compiles_total")}
+    with get_tracer().span("off"):
+        f, x = _fresh_jit()
+        f(x).block_until_ready()
+    after = {k: v for k, v in REGISTRY.snapshot().items()
+             if k.startswith("jax_compiles_total")}
+    assert after == snap
+    assert get_tracer().spans() == [] and get_tracer().instants() == []
+
+
 # ---------------------------------------------------------------------------
 # registry.py
 # ---------------------------------------------------------------------------
