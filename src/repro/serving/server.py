@@ -16,12 +16,14 @@ the Eq. 3 cost model (demand misses AND prefetch DMA), so
 latency/throughput reflect transfer traffic.
 
 Clock semantics (continuous server): the virtual clock counts measured
-host time for prefill + decode; jitted steps are pre-compiled in the
-constructor so no XLA compile lands on a request's latency. Prefill
-runs eagerly per prompt, so the first occurrence of a new prompt
-LENGTH still pays per-op trace overhead inside the clock — bucket
-prompt lengths upstream if tail latencies at many distinct lengths
-matter.
+host time for prefill + decode; the decode step is pre-compiled in the
+constructor so no XLA compile lands on a request's latency. Prefill is
+one jitted program per prompt shape, kept in JAX's cache: the first
+prefill of a new prompt LENGTH traces and compiles it inside the clock,
+every later one dispatches the cached executable
+(``serve_prefill_programs_total{outcome="built"|"reused"}`` counts
+which). Warm every prompt length before timing, and bucket lengths
+upstream if tail latencies at many distinct lengths matter.
 """
 from __future__ import annotations
 
@@ -39,6 +41,7 @@ from ..inference.engine import Request, ServingEngine, truncate_at_stop
 from ..inference.sampling import greedy, sample_per_row
 from ..models.model import decode_step, prefill
 from ..models.runtime import Runtime
+from ..obs.registry import REGISTRY
 from ..obs.trace import clock_span, get_tracer
 from .batch import BatchState
 from .metrics import ServerMetrics
@@ -95,9 +98,6 @@ class ContinuousBatchingServer:
         self.scheduler = scheduler or FCFSScheduler()
         self.n_slots = n_slots
         self.max_len = max_len
-        self.lora = lora
-        self.lora_scale = lora_scale
-        self.window_override = window_override
         self.seed = seed  # recorded in recovery checkpoints
         self._key0 = jax.random.key(seed)
 
@@ -108,6 +108,19 @@ class ContinuousBatchingServer:
             )
 
         self._decode_jit = jax.jit(_decode)
+
+        def _prefill(params, tokens):
+            # one executable per tokens shape in JAX's cache; returns the
+            # greedy first token (to match ServingEngine.generate_batch)
+            logits, cache = prefill(
+                params, cfg, tokens, self.rt, n_slots=max_len,
+                window_override=window_override, lora=lora, lora_scale=lora_scale,
+            )
+            return greedy(logits), cache
+
+        self._prefill_jit = jax.jit(_prefill)
+        # prompt lengths whose prefill program this server has built
+        self._prefill_lengths = {1}
 
         def _sample(logits, rids, steps, temps):
             # request-keyed per-row sampling: randomness follows the
@@ -133,9 +146,7 @@ class ContinuousBatchingServer:
             jnp.zeros((n_slots,), jnp.int32),
             jnp.ones((n_slots,), jnp.float32),
         )
-        _, pre = prefill(self.params, cfg, dummy[:1], self.rt, n_slots=max_len,
-                         window_override=window_override, lora=lora,
-                         lora_scale=lora_scale)
+        _, pre = self._prefill_jit(self.params, dummy[:1])
         self._insert_jit(self.cache, pre, 0)
 
     # ------------------------------------------------------------------
@@ -144,11 +155,7 @@ class ContinuousBatchingServer:
         structure (ring sizes etc.) to exactly what per-request prefills
         produce; rows are garbage until a request is inserted."""
         dummy = jnp.zeros((self.n_slots, 1), jnp.int32)
-        _, cache = prefill(
-            self.params, self.cfg, dummy, self.rt, n_slots=self.max_len,
-            window_override=self.window_override,
-            lora=self.lora, lora_scale=self.lora_scale,
-        )
+        _, cache = self._prefill_jit(self.params, dummy)
         cache["pos"] = jnp.zeros((self.n_slots,), jnp.int32)  # per-row positions
         return cache
 
@@ -183,23 +190,26 @@ class ContinuousBatchingServer:
         tr = get_tracer()
         inp = (req.prompt if req.resumed is None else
                np.concatenate([req.prompt, req.resumed]).astype(np.int32))
-        # host tracing, lowering and eager dispatch of the prefill
+        built = len(inp) not in self._prefill_lengths
+        self._prefill_lengths.add(len(inp))
+        REGISTRY.counter(
+            "serve_prefill_programs_total",
+            "continuous-server prefills, by whether their prompt length's "
+            "program was built or reused",
+            outcome="built" if built else "reused").inc()
+        # dispatch of this prompt length's cached prefill program; the
+        # first prefill of a length traces, lowers and compiles it here
         with tr.span("serve.prefill.forward"):
-            logits, pre_cache = prefill(
-                self.params, self.cfg, jnp.asarray(inp, jnp.int32)[None],
-                self.rt, n_slots=self.max_len,
-                window_override=self.window_override,
-                lora=self.lora, lora_scale=self.lora_scale,
-            )
+            first, pre_cache = self._prefill_jit(
+                self.params, jnp.asarray(inp[None], jnp.int32))
         with tr.span("serve.prefill.insert"):
             self.cache = self._insert_jit(self.cache, pre_cache, slot)
         state.occupy(slot, req, now)
         mt.prefill_tokens += len(inp)
-        # first generated token comes from the prefill logits (greedy, to
-        # match ServingEngine.generate_batch semantics); the host waits
-        # here for the device
+        # the host waits here for the device's prefill, then reads back
+        # the one greedy first token
         with tr.span("serve.prefill.first_token"):
-            tok = int(np.asarray(greedy(logits))[0, 0])
+            tok = int(np.asarray(first)[0, 0])
         cur[slot, 0] = tok
         mt.generated_tokens += 1
         return state.append_token(slot, tok)

@@ -71,6 +71,66 @@ def test_continuous_matches_single_request_engine(setup):
     assert len(mt.latencies) == len(reqs)
 
 
+def test_resumed_prefill_of_an_unseen_length_matches_the_eager_path(setup):
+    """Each prefill runs as one compiled program per prompt length. Its
+    greedy tokens equal the eager prefill's (``ServingEngine``) for
+    several lengths, and for resumed requests whose ``prompt + resumed``
+    lengths (9, 14) this server has not served before."""
+    cfg, params = setup
+    reqs = mk_requests(cfg, lens=[6, 11, 8], budgets=[8, 8, 8])
+    srv = ContinuousBatchingServer(cfg, params, n_slots=2, max_len=32)
+    full, _ = srv.run(RequestQueue(reqs))
+    resumed = [ServeRequest(rid=r.rid, prompt=r.prompt, max_new_tokens=8,
+                            resumed=f.tokens[:3])
+               for r, f in zip(reqs, full)]
+    again, _ = srv.run(RequestQueue(resumed))
+    eng = ServingEngine(cfg, params, max_batch=1)
+    for req, a, b in zip(reqs, full, again):
+        ref = eng.generate_batch(
+            [Request(prompt=req.prompt, max_new_tokens=8)])[0]
+        np.testing.assert_array_equal(a.tokens, ref.tokens)
+        np.testing.assert_array_equal(b.tokens, ref.tokens)
+
+
+def test_a_seen_prompt_length_reuses_its_prefill_program(setup):
+    """The first prefill of a prompt length builds its program; a later
+    prefill of that length compiles nothing, and
+    ``serve_prefill_programs_total`` books one "built" per new length and
+    "reused" for every other prefill."""
+    from repro.obs import REGISTRY, disable_tracing, enable_tracing
+
+    def programs():
+        return {o: REGISTRY.counter("serve_prefill_programs_total",
+                                    outcome=o).value
+                for o in ("built", "reused")}
+
+    def compiles():
+        return REGISTRY.counter("jax_compiles_total",
+                                span="serve.prefill.forward").value
+
+    cfg, params = setup
+    srv = ContinuousBatchingServer(cfg, params, n_slots=2, max_len=32)
+    p0 = programs()
+    srv.run(RequestQueue(mk_requests(cfg, lens=[7, 10, 7], budgets=[2, 2, 2])))
+    p1 = programs()
+    assert (p1["built"] - p0["built"], p1["reused"] - p0["reused"]) == (2, 1)
+    tr = enable_tracing()
+    try:
+        c0 = compiles()
+        srv.run(RequestQueue(mk_requests(cfg, lens=[10, 7, 10],
+                                         budgets=[2, 2, 2], seed=1)))
+        fwd = [s for s in tr.spans() if s.name == "serve.prefill.forward"]
+        assert len(fwd) == 3
+        assert compiles() == c0
+        assert not any("jax_compiles" in s.args for s in fwd)
+        srv.run(RequestQueue(mk_requests(cfg, lens=[13], budgets=[2], seed=2)))
+        assert compiles() - c0 == 1
+    finally:
+        disable_tracing()
+    p2 = programs()
+    assert (p2["built"] - p1["built"], p2["reused"] - p1["reused"]) == (1, 3)
+
+
 def test_continuous_beats_static_on_mixed_budgets(setup):
     """Acceptance: on a mixed-length workload, continuous batching emits
     the same tokens per request in strictly fewer decode iterations than
