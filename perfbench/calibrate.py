@@ -50,7 +50,8 @@ def main(argv=None) -> int:
     from perfbench.lib.traffic import load_traffic
 
     here = ROOT / "perfbench"
-    cj = load_config(here / "configs" / f"{cell['config']}.json")
+    cj, arch = load_config(here / "configs" / f"{cell['config']}.json",
+                           here / "archs")
     t = load_traffic(here / "traffic" / f"{cell['traffic']}.json")
     if args.rate is not None:
         t = {**t, "rate_rps": args.rate}
@@ -58,7 +59,7 @@ def main(argv=None) -> int:
     e2e = [m for m in bench["end_to_end"] if applies(m, cell, ())]
     t0 = T_START
     for seed in (int(s) for s in args.seeds.split(",")):
-        out = run_cell(cell, cj, t, e2e, here / "metrics", here / ".cache", seed,
+        out = run_cell(cell, cj, arch, t, e2e, here / "metrics", here / ".cache", seed,
                        args.seconds, False, t0, controls=controls)
         cmp = out["compared"]
         print(json.dumps({

@@ -44,19 +44,20 @@ def main(argv=None) -> int:
     sys.path.insert(0, str(ROOT / "src"))
     sys.path.insert(0, str(ROOT))
     from perfbench.lib import clock, readers, serve, stats, traffic
-    from perfbench.lib.model import load_config, make_weights, program_config
+    from perfbench.lib.model import load_config
     from repro.serving import RequestQueue
 
     here = ROOT / "perfbench"
-    cj = load_config(here / "configs" / f"{cell['config']}.json")
+    cj, arch = load_config(here / "configs" / f"{cell['config']}.json",
+                           here / "archs")
     t = traffic.load_traffic(here / "traffic" / f"{cell['traffic']}.json")
     if t["server"]["kind"] != "continuous":
         print("knee.py: sweeps continuous serving only", file=sys.stderr)
         return 2
     V = cj["vocab_size"]
-    params = make_weights(cj, args.seed)
+    params = arch.make_weights(cj, args.seed)
     warm = serve._serve_requests(serve._warm_requests(t, V, args.seed))
-    srv = serve.build_server(program_config(cj), cj, t, params, args.seed, warm)
+    srv = serve.build_server(arch, cj, t, params, args.seed, warm)
     del params
     srv.run(RequestQueue(warm))
     print(json.dumps({"step": "setup", "seconds": time.perf_counter() - T_START}),

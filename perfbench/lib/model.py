@@ -1,31 +1,35 @@
-"""Model configuration files and seeded weights.
+"""Model configuration files and the architecture module each names.
 
 A configuration file (``perfbench/configs/<name>.json``) holds the
 model as it is run, under the keys of its published ``config.json``.
-This module turns it into the program's ``ModelConfig`` and makes the
-weights from the seed, on the device, in one jitted call, in the
-parameter tree the program serves from. The plain reference
-(``reference.py``) reads the same file and the same tree.
+Its ``model_type`` names the architecture module
+``<archs_dir>/<model_type>.py`` that knows its shape keys: the
+program's ``ModelConfig``, the seeded weights, the parameter counts,
+each kernel's work and the plain reference (``perfbench/archs/``).
+This module checks only what every configuration states.
 """
 from __future__ import annotations
 
+import importlib.util
 import json
 from pathlib import Path
-from typing import Any, Dict
+from types import ModuleType
+from typing import Any, Dict, Tuple
 
 import numpy as np
 
 Json = Dict[str, Any]
 
+GENERIC_KEYS = ("name", "source", "model_type", "vocab_size", "torch_dtype",
+                "matmul_precision", "reduced", "param_bytes")
 
-def load_config(path: Path) -> Json:
+
+def load_config(path: Path, archs_dir: Path) -> Tuple[Json, ModuleType]:
+    """The configuration at ``path`` and its architecture module, found
+    by file path under ``archs_dir``; the module checks its own keys."""
     with open(path) as f:
         cj = json.load(f)
-    for key in ("hidden_size", "num_hidden_layers", "num_attention_heads",
-                "num_key_value_heads", "head_dim", "intermediate_size",
-                "num_local_experts", "num_experts_per_tok", "vocab_size",
-                "rope_theta", "rms_norm_eps", "qk_norm", "torch_dtype",
-                "matmul_precision"):
+    for key in GENERIC_KEYS:
         if key not in cj:
             raise KeyError(f"{path}: configuration lacks {key!r}")
     if cj["torch_dtype"] != "float32":
@@ -33,109 +37,19 @@ def load_config(path: Path) -> Json:
     if cj["matmul_precision"] != "default":
         raise ValueError(f"{path}: only JAX's default matmul precision is "
                          "benchmarked")
-    if cj["qk_norm"] not in ("none", "per_head"):
-        raise ValueError(f"{path}: qk_norm must be 'none' or 'per_head'")
-    return cj
-
-
-def param_count(cj: Json) -> Dict[str, int]:
-    """Parameters by part, from the configuration's sizes alone."""
-    d, L = cj["hidden_size"], cj["num_hidden_layers"]
-    hq, hkv, hd = (cj["num_attention_heads"], cj["num_key_value_heads"],
-                   cj["head_dim"])
-    E, k, f, V = (cj["num_local_experts"], cj["num_experts_per_tok"],
-                  cj["intermediate_size"], cj["vocab_size"])
-    attn = L * (2 * d * hq * hd + 2 * d * hkv * hd
-                + (2 * hd if cj["qk_norm"] == "per_head" else 0))
-    experts = L * E * 3 * d * f
-    router = L * d * E
-    norms = L * 2 * d + d
-    embed = V * d * (1 if cj.get("tie_word_embeddings") else 2)
-    total = attn + experts + router + norms + embed
-    # what one token multiplies through: attention, its top-k experts,
-    # the router and the head (the embedding is a gather)
-    active = attn + L * k * 3 * d * f + router + V * d
-    return {"attention": attn, "experts": experts, "router": router,
-            "norms": norms, "embed_head": embed, "total": total,
-            "active": active}
-
-
-def program_config(cj: Json):
-    """The program's ``ModelConfig`` for this file."""
-    from repro.configs.base import (AttnSpec, BlockSpec, LayoutGroup,
-                                    ModelConfig, MoESpec)
-
-    attn = AttnSpec(n_heads=cj["num_attention_heads"],
-                    n_kv_heads=cj["num_key_value_heads"],
-                    head_dim=cj["head_dim"], rope_theta=float(cj["rope_theta"]),
-                    qk_norm=cj["qk_norm"] == "per_head")
-    moe = MoESpec(num_experts=cj["num_local_experts"],
-                  top_k=cj["num_experts_per_tok"],
-                  d_ff=cj["intermediate_size"])
-    return ModelConfig(
-        name=cj["name"], family="moe", d_model=cj["hidden_size"],
-        vocab=cj["vocab_size"],
-        block_defs={"moe": BlockSpec(kind="attn_moe", attn=attn, moe=moe)},
-        layout=(LayoutGroup(("moe",), cj["num_hidden_layers"]),),
-        norm_eps=float(cj["rms_norm_eps"]),
-        tie_embeddings=bool(cj.get("tie_word_embeddings", False)),
-        dtype="float32", source=cj["source"])
+    mt = cj["model_type"]
+    arch_path = Path(archs_dir) / f"{mt}.py"
+    if not arch_path.is_file():
+        raise FileNotFoundError(f"{path}: no architecture module {arch_path} "
+                                f"for model_type {mt!r}")
+    spec = importlib.util.spec_from_file_location(
+        "perfbench_arch_" + mt.replace("-", "_").replace(".", "_"), arch_path)
+    arch = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(arch)
+    arch.check(cj)
+    return cj, arch
 
 
 def jax_seed(seed: int) -> int:
     """A 32-bit key seed from any whole-number ``--seed``."""
     return int(np.random.SeedSequence(seed).generate_state(1)[0])
-
-
-def make_weights(cj: Json, seed: int):
-    """Seeded weights in the program's parameter tree, made on the
-    device in one jitted call. Projections are N(0, 1/fan_in), norm
-    gains zero under the (1 + w) parameterisation; the router is float32
-    like every other leaf. An untied embedding is N(0, 1). A tied one is
-    also the head, so it is drawn as the head is, N(0, 1/hidden_size):
-    at N(0, 1) its logit for the token just read would be about
-    hidden_size and every greedy step would repeat that token."""
-    import jax
-    import jax.numpy as jnp
-
-    d, L = cj["hidden_size"], cj["num_hidden_layers"]
-    hq, hkv, hd = (cj["num_attention_heads"], cj["num_key_value_heads"],
-                   cj["head_dim"])
-    E, f, V = cj["num_local_experts"], cj["intermediate_size"], cj["vocab_size"]
-    f32 = jnp.float32
-
-    def dense(key, shape, fan_in):
-        return jax.random.normal(key, shape, f32) * (fan_in ** -0.5)
-
-    def init(key):
-        ks = jax.random.split(key, 10)
-        layer = {
-            "ln1": jnp.zeros((L, d), f32),
-            "ln2": jnp.zeros((L, d), f32),
-            "mixer": {
-                "wq": dense(ks[0], (L, d, hq * hd), d),
-                "wk": dense(ks[1], (L, d, hkv * hd), d),
-                "wv": dense(ks[2], (L, d, hkv * hd), d),
-                "wo": dense(ks[3], (L, hq * hd, d), hq * hd),
-            },
-            "ffn": {
-                "router": dense(ks[4], (L, d, E), d),
-                "wg": dense(ks[5], (L, E, d, f), d),
-                "wu": dense(ks[6], (L, E, d, f), d),
-                "wd": dense(ks[7], (L, E, f, d), f),
-            },
-        }
-        if cj["qk_norm"] == "per_head":
-            layer["mixer"]["q_norm"] = jnp.zeros((L, hd), f32)
-            layer["mixer"]["k_norm"] = jnp.zeros((L, hd), f32)
-        params = {
-            "embed": (dense(ks[8], (V, d), d) if cj.get("tie_word_embeddings")
-                      else jax.random.normal(ks[8], (V, d), f32)),
-            "final_norm": jnp.zeros((d,), f32),
-            "groups": {"g0": {"p0": layer}},
-        }
-        if not cj.get("tie_word_embeddings"):
-            params["lm_head"] = dense(ks[9], (d, V), d)
-        return params
-
-    return jax.jit(init)(jax.random.key(jax_seed(seed)))

@@ -1,17 +1,12 @@
-"""Plain reference of the served model, and the comparison that decides
-``correct``.
+"""The comparison that decides ``correct``, and the pieces that every
+architecture's plain reference shares.
 
-The forward pass is written from the configuration file alone, in
-straightforward ``jax.numpy``: token embedding; per layer a pre-norm
-grouped-query attention block (rotary positions, optional per-head
-q/k RMSNorm, causal softmax at 1/sqrt(head_dim)) and a pre-norm MoE
-block (float32 softmax router over all experts, the top-k raw
-probabilities as gates, SwiGLU experts); a final norm and the head.
-RMSNorm gains use the (1 + w) form. It imports nothing of the program
-and takes only the benchmark's own weights. The MoE is computed
-densely (every expert for every token, weighted by a gate that is zero
-off the top-k), so no dispatch, capacity or cache is shared with the
-program.
+Each architecture module under ``perfbench/archs/`` writes its forward
+pass from the configuration file alone, in straightforward
+``jax.numpy`` with every matmul in float32 at HIGHEST precision,
+importing nothing of the program and taking only the benchmark's own
+weights. The helpers here are its parts: RMSNorm in the (1 + w) form,
+rotary positions, and the rounding of a lower-precision control.
 
 The comparison: for each served token, the gap by which the
 reference's logit for it lies below the reference's best logit at that
@@ -24,14 +19,14 @@ lower precision apart from the program's.
 """
 from __future__ import annotations
 
-from typing import Dict, List, Sequence
+from typing import Callable, Dict, List, Sequence
 
 import numpy as np
 
 BUCKET = 512  # sequences pad to a multiple of this: few shapes, few compiles
 
 
-def _rms(x, w, eps):
+def rms(x, w, eps):
     import jax.numpy as jnp
 
     dt = x.dtype
@@ -40,7 +35,7 @@ def _rms(x, w, eps):
     return (y * (1.0 + w.astype(jnp.float32))).astype(dt)
 
 
-def _rope(x, theta):
+def rope(x, theta):
     """Rotate halves: x (T, H, hd) at positions 0..T-1, angles in f32."""
     import jax.numpy as jnp
 
@@ -55,7 +50,7 @@ def _rope(x, theta):
 CONTROLS = ("int8", "fp8")  # the precisions a control may round to
 
 
-def _round(x, axis, kind):
+def round_to(x, axis, kind):
     """Symmetric rounding to ``kind`` along ``axis``, one scale per slice
     (the slice's largest magnitude maps to the format's largest value),
     returned in float32."""
@@ -69,73 +64,20 @@ def _round(x, axis, kind):
     return (x / s).astype(jnp.float8_e4m3fn).astype(jnp.float32) * s
 
 
-def forward(cj: dict, params, tokens, quant: str = ""):
-    """Logits (T, V) for one sequence ``tokens`` (T,), every matmul in
-    float32 at HIGHEST precision. ``quant`` names a control: "int8" or
-    "fp8" (e4m3) rounds every matmul weight per output channel and every
-    projection's input per row to that format, and multiplies the
-    rounded values in float32."""
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-
-    prec = lax.Precision.HIGHEST
-    act = (lambda a: _round(a, -1, quant)) if quant else (lambda a: a)
-    wq = (lambda w: _round(w, -2, quant)) if quant else (lambda w: w)
-    mm = lambda a, b: jnp.matmul(act(a), b, precision=prec)
-    eps = float(cj["rms_norm_eps"])
-    hq, hkv, hd = (cj["num_attention_heads"], cj["num_key_value_heads"],
-                   cj["head_dim"])
-    k_top, theta = cj["num_experts_per_tok"], float(cj["rope_theta"])
-    qk_norm = cj["qk_norm"] == "per_head"
-    p = jax.tree.map(lambda a: a.astype(jnp.float32), params)
-    head = p["embed"].T if cj.get("tie_word_embeddings") else p["lm_head"]
-    if quant:  # matrices of the layers and the head; the embedding is a gather
-        p = {**p, "groups": jax.tree.map(lambda a: wq(a) if a.ndim >= 3 else a,
-                                         p["groups"])}
-        head = wq(head)
-    T = tokens.shape[0]
-    x = p["embed"][tokens]
-    causal = jnp.tril(jnp.ones((T, T), bool))
-
-    def layer(x, lp):
-        a = lp["mixer"]
-        h = _rms(x, lp["ln1"], eps)
-        q = mm(h, a["wq"]).reshape(T, hq, hd)
-        k = mm(h, a["wk"]).reshape(T, hkv, hd)
-        v = mm(h, a["wv"]).reshape(T, hkv, hd)
-        if qk_norm:
-            q, k = _rms(q, a["q_norm"], eps), _rms(k, a["k_norm"], eps)
-        q, k = _rope(q, theta), _rope(k, theta)
-        k = jnp.repeat(k, hq // hkv, axis=1)
-        v = jnp.repeat(v, hq // hkv, axis=1)
-        s = jnp.einsum("thd,shd->hts", q, k, precision=prec) * (hd ** -0.5)
-        s = jnp.where(causal[None], s, -jnp.inf)
-        w = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("hts,shd->thd", w, v, precision=prec).reshape(T, hq * hd)
-        x = x + mm(o, a["wo"])
-
-        f = lp["ffn"]
-        h2 = _rms(x, lp["ln2"], eps)
-        probs = jax.nn.softmax(mm(h2, f["router"]).astype(jnp.float32), -1)
-        kth = lax.top_k(probs, k_top)[0][:, -1:]
-        gates = jnp.where(probs >= kth, probs, 0.0)  # (T, E)
-        g = jnp.einsum("td,edf->tef", act(h2), f["wg"], precision=prec)
-        u = jnp.einsum("td,edf->tef", act(h2), f["wu"], precision=prec)
-        y = jnp.einsum("tef,efd->ted", act(jax.nn.silu(g) * u), f["wd"],
-                       precision=prec)
-        x = x + jnp.einsum("ted,te->td", y, gates, precision=prec)
-        return x, None
-
-    x, _ = lax.scan(layer, x, p["groups"]["g0"]["p0"])
-    x = _rms(x, p["final_norm"], eps)
-    return mm(x, head)
+def rounding(quant: str):
+    """``(act, wq)`` for a control named ``quant`` ("" for none): ``act``
+    rounds a projection's input per row, ``wq`` a matmul weight per
+    output channel, each to that format and back to float32."""
+    if not quant:
+        return (lambda a: a), (lambda w: w)
+    return (lambda a: round_to(a, -1, quant)), (lambda w: round_to(w, -2, quant))
 
 
 class Reference:
-    """Jitted reference passes, one compile per padded length bucket."""
+    """Jitted passes of an architecture's ``forward``, one compile per
+    padded length bucket."""
 
-    def __init__(self, cj: dict):
+    def __init__(self, cj: dict, forward: Callable):
         import jax
 
         self.cj = cj

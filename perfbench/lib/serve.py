@@ -23,7 +23,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence
 import numpy as np
 
 from . import clock, reference, traffic as traffic_mod
-from .model import jax_seed, make_weights, param_count, program_config
+from .model import jax_seed
 
 DRAIN_LIMIT_S = 150.0  # after the window: stop admitting, finish in flight
 PROFILE_AT = 0.3  # traced run: the profiler starts this far into the window
@@ -39,6 +39,7 @@ class Context:
 
     cell: dict
     cj: dict
+    arch: Any  # the configuration's architecture module (perfbench/archs)
     traffic: dict
     kind: str  # "continuous" | "wave"
     seconds: float
@@ -103,13 +104,14 @@ def _warm_requests(t: dict, vocab: int, seed: int):
             for i, (L, m) in enumerate(sizes)]
 
 
-def build_server(cfg, cj, t, params, seed, reqs):
+def build_server(arch, cj, t, params, seed, reqs):
     """The served path, as ``bench_serve`` builds it (kernels ``auto``):
     the mix's ``server`` names the server, its scheduler and its sizes."""
     from repro.models.runtime import Runtime
     from repro.serving import (ContinuousBatchingServer, OffloadedWaveServer,
                                get_scheduler, prefill_expert_scores)
 
+    cfg = arch.program_config(cj)
     s = t["server"]
     rt = Runtime(zero_drop=True, kernel_backend="auto")
     name = s.get("scheduler", "fcfs")
@@ -119,8 +121,8 @@ def build_server(cfg, cj, t, params, seed, reqs):
             scheduler=get_scheduler(name), rt=rt, seed=jax_seed(seed))
     if s["kind"] == "wave":
         prefill_expert_scores(cfg, params, reqs, rt=rt)  # oracle profiles
-        capacity = max(cj["num_experts_per_tok"],
-                       int(cj["num_local_experts"] * s["capacity_frac"]))
+        held, top_k = arch.experts(cj)
+        capacity = max(top_k, int(held * s["capacity_frac"]))
         kw = {"top_c": capacity} if name == "expert-affinity" else {}
         return OffloadedWaveServer(
             cfg, params, capacity=capacity, scheduler=get_scheduler(name, **kw),
@@ -162,29 +164,28 @@ class _Profiler(threading.Thread):
             self.done.set()
 
 
-def run_cell(cell: dict, cj: dict, t: dict, metric_entries: List[dict],
+def run_cell(cell: dict, cj: dict, arch, t: dict, metric_entries: List[dict],
              metrics_dir: Path, work_dir: Path, seed: int, seconds: float,
              trace: bool, t_start: float, fault: Optional[Callable] = None,
              controls: Sequence[str] = ()) -> dict:
-    """Run ``cell`` (configuration ``cj``, traffic ``t``) once and return
-    what the result line needs. ``work_dir`` takes the device trace.
-    ``fault``, for tests, is called with the warmed server and may break
-    the timed path; ``controls`` (for calibration) are judged beside the
-    program."""
+    """Run ``cell`` (configuration ``cj`` of architecture module ``arch``,
+    traffic ``t``) once and return what the result line needs.
+    ``work_dir`` takes the device trace. ``fault``, for tests, is called
+    with the warmed server and may break the timed path; ``controls``
+    (for calibration) are judged beside the program."""
     import jax
 
     kind = t["server"]["kind"]
-    cfg = program_config(cj)
     dev = jax.devices()[0]
 
     # ---- set-up: weights, requests, server, warm-up -----------------
-    params = make_weights(cj, seed)
+    params = arch.make_weights(cj, seed)
     jax.block_until_ready(params)
     bytes_on_device = sum(x.nbytes for x in jax.tree.leaves(params))
     reqs = traffic_mod.build_requests(t, cj["vocab_size"], seed, seconds)
     sreqs = _serve_requests(reqs)
     warm = _serve_requests(_warm_requests(t, cj["vocab_size"], seed))
-    srv = build_server(cfg, cj, t, params, seed, sreqs + warm)
+    srv = build_server(arch, cj, t, params, seed, sreqs + warm)
     del params  # the server holds what it serves from; freed with it
     from repro.serving import RequestQueue
 
@@ -193,7 +194,7 @@ def run_cell(cell: dict, cj: dict, t: dict, metric_entries: List[dict],
         fault(srv)
     engine0 = _engine_counters(srv) if kind == "wave" else {}
     print(f"bench: config {cj['name']}: declared parameter bytes "
-          f"{cj['param_bytes']}, from the sizes {4 * param_count(cj)['total']}, "
+          f"{cj['param_bytes']}, from the sizes {4 * arch.param_count(cj)['total']}, "
           f"made on the device {bytes_on_device}", file=sys.stderr)
 
     # ---- the measured window ----------------------------------------
@@ -243,8 +244,8 @@ def run_cell(cell: dict, cj: dict, t: dict, metric_entries: List[dict],
                      "kernel_sites": _kernel_sites(),
                      "latency_ms": _latency_ms(st, q, attempted)})
 
-    ctx = Context(cell=cell, cj=cj, traffic=t, kind=kind, seconds=seconds,
-                  setup_s=setup_s, t0=t0,
+    ctx = Context(cell=cell, cj=cj, arch=arch, traffic=t, kind=kind,
+                  seconds=seconds, setup_s=setup_s, t0=t0,
                   due_abs={rid: q.due_at(rid) for rid in attempted},
                   stamps=st, attempted=attempted, counters=counters)
     if trace:
@@ -269,7 +270,7 @@ def run_cell(cell: dict, cj: dict, t: dict, metric_entries: List[dict],
                 for rid in ok}
     del srv, results, by_rid
     gc.collect()
-    check = compare(cj, t, seed, finished, controls)
+    check = compare(arch, cj, t, seed, finished, controls)
     out = {
         "correct": check["ok"] and failed == 0 and len(attempted) > 0,
         "attempted": len(attempted),
@@ -328,17 +329,18 @@ def judge(gaps: np.ndarray, c: dict) -> dict:
             "checks": {"mean_logit_gap": {"value": mean, "limit": limit}}}
 
 
-def compare(cj: dict, t: dict, seed: int, finished: Dict[int, tuple],
+def compare(arch, cj: dict, t: dict, seed: int, finished: Dict[int, tuple],
             controls: Sequence[str] = ()) -> dict:
     """Compare a seeded sample of the finished requests with the
-    reference and judge the program by it. Each control named in
-    ``controls`` (a lower precision of the reference, put in the
-    program's place at the same positions) is judged by the same rule."""
+    architecture's plain reference and judge the program by it. Each
+    control named in ``controls`` (a lower precision of the reference,
+    put in the program's place at the same positions) is judged by the
+    same rule."""
     c = t["correct"]
     rids = reference.pick_sample(finished, seed, c["sample_tokens"],
                                  c["max_requests"])
-    params = make_weights(cj, seed)
-    ref = reference.Reference(cj)
+    params = arch.make_weights(cj, seed)
+    ref = reference.Reference(cj, arch.forward)
     parts: Dict[str, list] = {"program": [], **{q: [] for q in controls}}
     for rid in rids:
         prompt, served = finished[rid]

@@ -8,7 +8,7 @@ work moves its roofline share, not its work.
 """
 from __future__ import annotations
 
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 F32 = 4
 
@@ -37,23 +37,31 @@ def gmm(rows: int, k: int, n: int, experts: int) -> Work:
     return Work(2.0 * rows * k * n, F32 * (experts * k * n + rows * (k + n)))
 
 
-def moe_layer_gmms(tokens: int, cj: dict) -> Work:
-    """The three grouped matmuls of one MoE layer (gate, up, down) for
-    ``tokens`` tokens, each routed to top-k experts."""
-    d, f = cj["hidden_size"], cj["intermediate_size"]
-    E, k = cj["num_local_experts"], cj["num_experts_per_tok"]
-    rows = tokens * k
-    return gmm(rows, d, f, E) + gmm(rows, d, f, E) + gmm(rows, f, d, E)
+def moe_gmms(tokens: int, d: int, f: int, experts: int, top_k: int) -> Work:
+    """The three grouped matmuls of one MoE layer (gate, up, down) of
+    width ``d`` and expert width ``f``, for ``tokens`` tokens each routed
+    to ``top_k`` of the ``experts`` experts held."""
+    rows = tokens * top_k
+    return gmm(rows, d, f, experts) + gmm(rows, d, f, experts) + gmm(rows, f, d, experts)
 
 
-def causal_attention(tokens: int, cj: dict) -> Work:
-    """One layer's causal self-attention over a prompt of ``tokens``:
-    scores and the weighted sum for the (T + 1) / 2 keys each query sees
-    on average, reading q, k and v once and writing the output once."""
-    hq, hkv, hd = (cj["num_attention_heads"], cj["num_key_value_heads"],
-                   cj["head_dim"])
-    pairs = tokens * (tokens + 1) / 2
+def causal_attention(tokens: int, hq: int, hkv: int, hd: int,
+                     window: Optional[int] = None) -> Work:
+    """One layer's causal self-attention over a prompt of ``tokens``, with
+    ``hq`` query and ``hkv`` key/value heads of ``hd``: scores and the
+    weighted sum for the keys each query sees, reading q, k and v once
+    and writing the output once. Query t sees t + 1 keys, or
+    ``min(t + 1, window)`` in a sliding window."""
+    if window is None or window >= tokens:
+        pairs = tokens * (tokens + 1) / 2
+    else:
+        pairs = window * (window + 1) / 2 + (tokens - window) * window
     return Work(4.0 * hq * hd * pairs, F32 * tokens * hd * (2 * hq + 2 * hkv))
+
+
+def times(n: int, w: Work) -> Work:
+    """``w`` done ``n`` times, as in ``n`` identical layers."""
+    return Work(n * w.flops, n * w.bytes)
 
 
 def model_flops(tokens: int, active_params: int) -> float:

@@ -1,11 +1,11 @@
 """Whole decode step on the device: model FLOPs (two per active
-parameter) of the active rows' tokens, over the device-busy time inside
-the traced ``serve.decode_step`` spans at the chip's bf16 peak, in %."""
+parameter, the architecture's ``param_count``) of the active rows'
+tokens, over the device-busy time inside the traced
+``serve.decode_step`` spans at the chip's bf16 peak, in %."""
 from perfbench.lib import readers, work
-from perfbench.lib.model import param_count
 
 
 def read(ctx):
-    active = param_count(ctx.cj)["active"]
+    active = ctx.arch.param_count(ctx.cj)["active"]
     return readers.step_mfu(ctx, "serve.decode_step",
                             lambda s: work.model_flops(s.args.get("active", 0), active))

@@ -1,5 +1,5 @@
 """Every gap between two consecutive tokens of every request attempted,
-95th percentile (continuous serving, where tokens stream)."""
+90th percentile (continuous serving, where tokens stream)."""
 from perfbench.lib import readers, stats
 
 
@@ -7,4 +7,4 @@ def read(ctx):
     if ctx.kind != "continuous":
         return None
     times = {rid: ctx.stamps.token_times.get(rid, []) for rid in ctx.attempted}
-    return readers.ms_percentile(stats.token_gaps_s(times), 95)
+    return readers.ms_percentile(stats.token_gaps_s(times), 90)

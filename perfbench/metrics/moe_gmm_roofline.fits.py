@@ -1,19 +1,16 @@
 """Kernel ``moe_gmm``: the least time the chip could take for the
 grouped matmuls of the traced steps (routed rows and the weights of
-the experts each call is given, ``lib/work.py``) over the device time
-of the ``moe_gmm`` operations, in %. Prefill spans route their prompt
-tokens, decode spans their active rows, through every layer."""
-from perfbench.lib import readers, work
+the experts each call is given, summed over the layers that run it by
+the architecture's ``kernel_work``) over the device time of the
+``moe_gmm`` operations, in %. Prefill spans route their prompt tokens,
+decode spans their active rows."""
+from perfbench.lib import readers
 
 
 def read(ctx):
-    L = ctx.cj["num_hidden_layers"]
-    sw = [(a, b, _times(L, work.moe_layer_gmms(s.args["prompt_len"], ctx.cj)))
+    work_of = lambda n: ctx.arch.kernel_work(ctx.cj, "moe_gmm", n)
+    sw = [(a, b, work_of(s.args["prompt_len"]))
           for s, a, b in readers.traced_spans(ctx, "serve.prefill")]
-    sw += [(a, b, _times(L, work.moe_layer_gmms(s.args["active"], ctx.cj)))
+    sw += [(a, b, work_of(s.args["active"]))
            for s, a, b in readers.traced_spans(ctx, "serve.decode_step")]
     return readers.roofline_share(ctx, "moe_gmm", sw)
-
-
-def _times(n, w):
-    return work.Work(n * w.flops, n * w.bytes)

@@ -1,14 +1,14 @@
 """Whole prefill on the device: model FLOPs of each traced prompt (two
 per active parameter per prompt token, the head for the last position
-only, which is all that prefill returns) over the device-busy time
-inside the traced ``serve.prefill`` spans at the chip's bf16 peak, in %."""
+only, which is all that prefill returns; the architecture's
+``param_count``) over the device-busy time inside the traced
+``serve.prefill`` spans at the chip's bf16 peak, in %."""
 from perfbench.lib import readers, work
-from perfbench.lib.model import param_count
 
 
 def read(ctx):
-    pc = param_count(ctx.cj)
-    head = ctx.cj["vocab_size"] * ctx.cj["hidden_size"]
+    pc = ctx.arch.param_count(ctx.cj)
+    head = pc["head"]
 
     def flops(s):
         T = s.args.get("prompt_len", 0)

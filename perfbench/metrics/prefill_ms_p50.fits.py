@@ -1,5 +1,5 @@
 """Model step: median duration of the program's ``serve.prefill`` spans
-in the window (eager prefill of one prompt, ended by a host sync)."""
+in the window (one prompt's prefill, ended by a host sync)."""
 from perfbench.lib import readers
 
 

@@ -5,10 +5,10 @@
         --seconds 40 --trace 0
 
 Reads ``BENCHMARK.json`` at the root of the checkout, finds the cell and
-its configuration, traffic and metric files by name, runs the served
-path for ``--seconds`` on the wall clock after set-up, compares what it
-served with the plain reference, and prints one JSON object as the last
-line of stdout. ``--trace 1`` reports the per-layer metrics (from the
+its configuration, architecture, traffic and metric files by name, runs
+the served path for ``--seconds`` on the wall clock after set-up,
+compares what it served with the plain reference, and prints one JSON
+object as the last line of stdout. ``--trace 1`` reports the per-layer metrics (from the
 program's spans and counters and a device trace of part of the window)
 instead of the end-to-end ones.
 
@@ -90,7 +90,9 @@ def main(argv=None) -> int:
     wanted = ([m for m in bench["per_layer"] if applies(m, cell, e2e_names)]
               if args.trace else e2e)
     here = ROOT / "perfbench"
-    out = run_cell(cell, load_config(here / "configs" / f"{cell['config']}.json"),
+    cj, arch = load_config(here / "configs" / f"{cell['config']}.json",
+                           here / "archs")
+    out = run_cell(cell, cj, arch,
                    load_traffic(here / "traffic" / f"{cell['traffic']}.json"),
                    wanted, here / "metrics", here / ".cache", args.seed,
                    args.seconds, bool(args.trace), T_START)

@@ -10,7 +10,7 @@ import numpy as np
 import pytest
 
 from perfbench.lib import peaks, readers, stats, trace_reduce, traffic, work
-from perfbench.lib.model import load_config, param_count
+from perfbench.lib.model import load_config
 
 BENCH = Path(__file__).resolve().parents[1]
 DATA = Path(__file__).resolve().parent / "data"
@@ -57,8 +57,8 @@ def test_benchmark_files_resolve():
     each configuration states its parameter bytes as its sizes give them."""
     bench = json.loads((BENCH.parent / "BENCHMARK.json").read_text())
     for c in bench["configs"]:
-        cj = load_config(BENCH.parent / c["file"])
-        assert 4 * param_count(cj)["total"] == cj["param_bytes"], c["name"]
+        cj, arch = load_config(BENCH.parent / c["file"], BENCH / "archs")
+        assert 4 * arch.param_count(cj)["total"] == cj["param_bytes"], c["name"]
         for key in c["reduced"]:
             assert key in cj["reduced"], (c["name"], key)
     for w in bench["workloads"]:
@@ -95,7 +95,7 @@ def test_end_to_end_readers_on_made_up_stamps():
     assert _read("setup_s")(ctx) == 3.5
     assert _read("ttft_p90_ms")(ctx) == pytest.approx(1e3 * (0.1 + 0.9 * 0.3))
     gaps = [0.1, 1.5, 0.2]
-    assert _read("itl_p95_ms")(ctx) == pytest.approx(1e3 * np.percentile(gaps, 95))
+    assert _read("itl_p90_ms")(ctx) == pytest.approx(1e3 * np.percentile(gaps, 90))
     assert _read("output_tok_s")(ctx) == pytest.approx(4 / 2.0)  # 12.1 is out
     ctx.kind = "wave"
     assert _read("ttft_p90_ms")(ctx) is None
@@ -158,9 +158,7 @@ def test_from_xspace_reads_device_ops_and_host_spans():
 
 def test_roofline_reader_never_passes_100_for_honest_times():
     """A kernel timed at exactly its bound reads 100%; slower reads less."""
-    cj = {"hidden_size": 1024, "intermediate_size": 512, "num_local_experts": 32,
-          "num_experts_per_tok": 8, "num_hidden_layers": 1}
-    w = work.moe_layer_gmms(16, cj)
+    w = work.moe_gmms(16, d=1024, f=512, experts=32, top_k=8)
     pk = peaks.peaks_for("TPU v5 lite")
     t_min = w.min_seconds(pk.flops_bf16, pk.hbm_bytes_s)
     for slow in (1.0, 2.5):
@@ -180,20 +178,23 @@ def test_kernel_work_on_known_shapes():
     assert g.flops == 2 * 8 * 4 * 2
     assert g.bytes == 4 * (3 * 4 * 2 + 8 * (4 + 2))
     # decode of one token through granite's MoE layer is bound by weights
-    cj = {"hidden_size": 1024, "intermediate_size": 512, "num_local_experts": 32,
-          "num_experts_per_tok": 8}
     pk = peaks.peaks_for("TPU v5 lite")
-    assert work.moe_layer_gmms(1, cj).bound(pk.flops_bf16, pk.hbm_bytes_s) == "memory"
+    w = work.moe_gmms(1, d=1024, f=512, experts=32, top_k=8)
+    assert w.bound(pk.flops_bf16, pk.hbm_bytes_s) == "memory"
     assert work.model_flops(3, 10) == 60
 
 
 def test_attention_work_on_known_shapes():
     """Causal attention over T tokens: each query sees (T + 1) / 2 keys
-    on average; q, k, v are read and the output written once."""
-    cj = {"num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 8}
-    w = work.causal_attention(3, cj)
+    on average; q, k, v are read and the output written once. In a
+    sliding window query t sees min(t + 1, window) keys."""
+    w = work.causal_attention(3, hq=4, hkv=2, hd=8)
     assert w.flops == 4.0 * 4 * 8 * 6  # pairs (t, s <= t): 1 + 2 + 3
     assert w.bytes == 4 * 3 * 8 * (2 * 4 + 2 * 2)
+    assert work.causal_attention(3, 4, 2, 8, window=3) == w
+    win = work.causal_attention(5, hq=4, hkv=2, hd=8, window=2)
+    assert win.flops == 4.0 * 4 * 8 * (1 + 2 + 2 + 2 + 2)
+    assert win.bytes == 4 * 5 * 8 * (2 * 4 + 2 * 2)
 
 
 def test_peaks_refuse_an_unknown_device_kind():

@@ -16,11 +16,12 @@ BENCH = Path(__file__).resolve().parents[1]
 DATA = Path(__file__).resolve().parent / "data"
 
 
-def _run(traffic_file, fault=None, seconds=2.0, seed=2**32 + 7):
-    cj = load_config(DATA / "tiny.json")
+def _run(traffic_file, fault=None, seconds=2.0, seed=2**32 + 7,
+         config=DATA / "tiny.json", archs=BENCH / "archs"):
+    cj, arch = load_config(config, archs)
     t = traffic.load_traffic(DATA / traffic_file)
     ms = [{"name": "output_tok_s", "unit": "tokens/s"}]
-    return serve.run_cell({"name": "tiny"}, cj, t, ms, BENCH / "metrics",
+    return serve.run_cell({"name": cj["name"]}, cj, arch, t, ms, BENCH / "metrics",
                           BENCH / ".cache" / "tests", seed, seconds, False,
                           time.perf_counter(), fault=fault)
 
@@ -70,6 +71,27 @@ def test_a_broken_timed_path_is_not_correct(fault):
     assert gap["value"] > gap["limit"]
 
 
+@pytest.mark.parametrize("fault", [None, _alter_tokens], ids=["sound", "token_altered"])
+def test_an_architecture_added_as_files_is_judged_by_its_reference(fault):
+    """``tinymix`` lives only under ``tests/data``: a leading dense layer,
+    then windowed and full attention with a shared expert, served on
+    prompts longer than its window."""
+    import repro.serving.server as server_mod
+
+    real = server_mod.greedy
+    try:
+        out = _run("tiny-chat.json", fault=fault, config=DATA / "tinymix.json",
+                   archs=DATA / "archs")
+    finally:
+        server_mod.greedy = real
+    gap = out["checks"]["mean_logit_gap"]
+    assert out["attempted"] == 12 and out["failed"] == 0
+    if fault is None:
+        assert out["correct"] and gap["value"] <= gap["limit"], out["checks"]
+    else:
+        assert not out["correct"] and gap["value"] > gap["limit"]
+
+
 def _alter_engine_tokens(srv):
     """Offloaded path: the engine's next token altered where it is made."""
     real = srv.engine._next_tok_fn
@@ -97,14 +119,13 @@ def test_the_lower_precision_control_is_not_correct(control):
     """The reference rounded to a lower precision, put in the program's
     place at every position of the served sequences, is judged by the
     rule that judges a run, and comes out not correct."""
-    cj = load_config(DATA / "tiny.json")
+    cj, arch = load_config(DATA / "tiny.json", BENCH / "archs")
     t = traffic.load_traffic(DATA / "tiny-chat.json")
     t = {**t, "correct": {**t["correct"], "sample_tokens": 10**6, "max_requests": 10**6}}
     # greedy decoding by the float32 reference itself: gap 0 by construction
     from perfbench.lib import reference
-    from perfbench.lib.model import make_weights
 
-    ref, params = reference.Reference(cj), make_weights(cj, 9)
+    ref, params = reference.Reference(cj, arch.forward), arch.make_weights(cj, 9)
     rng = np.random.default_rng(4)
     finished = {}
     for i in range(6):
@@ -113,7 +134,7 @@ def test_the_lower_precision_control_is_not_correct(control):
         for _ in range(20):
             seq.append(int(np.asarray(ref.logits(params, np.asarray(seq)))[-1].argmax()))
         finished[i] = (p, np.asarray(seq[16:], np.int32))
-    got = serve.compare(cj, t, 9, finished, controls=[control])
+    got = serve.compare(arch, cj, t, 9, finished, controls=[control])
     gap = got["checks"]["mean_logit_gap"]
     assert got["ok"] and gap["value"] <= gap["limit"]
     ctl = got["controls"][control]
