@@ -4,10 +4,12 @@ from .base import (
     BlockSpec,
     LayoutGroup,
     MelinoeSpec,
+    MLASpec,
     ModelConfig,
     MoESpec,
     ShapeSpec,
     SSMSpec,
+    YarnSpec,
     make_smoke,
 )
 from .registry import ASSIGNED, PAPER, get_config, list_archs
@@ -18,10 +20,12 @@ __all__ = [
     "BlockSpec",
     "LayoutGroup",
     "MelinoeSpec",
+    "MLASpec",
     "ModelConfig",
     "MoESpec",
     "ShapeSpec",
     "SSMSpec",
+    "YarnSpec",
     "make_smoke",
     "ASSIGNED",
     "PAPER",

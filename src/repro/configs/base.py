@@ -11,7 +11,7 @@ from __future__ import annotations
 import dataclasses
 import math
 from dataclasses import dataclass, field, replace
-from typing import Mapping, Optional, Tuple
+from typing import Mapping, Optional, Tuple, Union
 
 # ---------------------------------------------------------------------------
 # Sub-specs
@@ -45,6 +45,48 @@ class AttnSpec:
 
 
 @dataclass(frozen=True)
+class YarnSpec:
+    """YaRN rotary scaling (arXiv:2309.00071) as DeepSeek-V2 sets it:
+    frequencies past the correction range are divided by ``factor``,
+    and the softmax scale is multiplied by ``m**2`` with
+    ``m = 0.1 * mscale_all_dim * ln(factor) + 1``."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclass(frozen=True)
+class MLASpec:
+    """Multi-head latent attention (DeepSeek-V2) without a query LoRA.
+
+    Each head's query is ``qk_nope_head_dim`` + ``qk_rope_head_dim``
+    wide; keys and values come from one ``kv_lora_rank``-wide latent
+    per token, and the rotary part of the key is one
+    ``qk_rope_head_dim``-wide vector that all heads share."""
+
+    n_heads: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    rope_theta: float = 10_000.0
+    yarn: Optional[YarnSpec] = None
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def latent_dim(self) -> int:
+        """Floats cached per token: the latent and the shared rope key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+
+@dataclass(frozen=True)
 class MoESpec:
     """Sparsely-gated expert FFN spec (Eq. 1-2 of the paper)."""
 
@@ -55,9 +97,17 @@ class MoESpec:
     shared_d_ff: int = 0  # fused hidden dim of the shared expert block
     capacity_factor: float = 1.25
     router_softcap: Optional[float] = None
+    # routed experts held here (ids 0 .. num_held-1): one chip's share of
+    # an expert-parallel layer; None holds all of them
+    num_held: Optional[int] = None
 
     def __post_init__(self):
         assert 0 < self.top_k <= self.num_experts
+        assert 0 < self.held <= self.num_experts
+
+    @property
+    def held(self) -> int:
+        return self.num_experts if self.num_held is None else self.num_held
 
     def capacity(self, n_tokens: int) -> int:
         """GShard-style per-expert capacity."""
@@ -90,7 +140,7 @@ class BlockSpec:
     """One transformer-ish block: pre-norm + mixer + pre-norm + channel-mixer."""
 
     kind: str  # "attn_dense" | "attn_moe" | "mamba" | "shared_attn"
-    attn: Optional[AttnSpec] = None
+    attn: Optional[Union[AttnSpec, MLASpec]] = None
     d_ff: int = 0  # dense (gated) MLP hidden dim; 0 => no MLP (pure mamba block)
     moe: Optional[MoESpec] = None
     ssm: Optional[SSMSpec] = None
@@ -204,7 +254,15 @@ class ModelConfig:
         for name in self.blocks_in_order():
             b = self.block_defs[name]
             t = a = 0
-            if b.attn is not None:
+            if isinstance(b.attn, MLASpec):
+                s = b.attn
+                attn_p = (d * s.n_heads * s.qk_head_dim + d * s.latent_dim
+                          + s.kv_lora_rank
+                          + s.kv_lora_rank * s.n_heads * (s.qk_nope_head_dim + s.v_head_dim)
+                          + s.n_heads * s.v_head_dim * d)
+                t += attn_p
+                a += attn_p
+            elif b.attn is not None:
                 s = b.attn
                 attn_p = d * s.q_dim + 2 * d * s.kv_dim + s.q_dim * d
                 if s.qk_norm:
@@ -217,8 +275,8 @@ class ModelConfig:
                 a += mlp_p
             if b.moe is not None:
                 m = b.moe
-                t += m.num_experts * 3 * d * m.d_ff + m.num_experts * d  # experts + router
-                a += m.top_k * 3 * d * m.d_ff + m.num_experts * d
+                t += m.held * 3 * d * m.d_ff + m.num_experts * d  # experts + router
+                a += m.top_k * m.held // m.num_experts * 3 * d * m.d_ff + m.num_experts * d
                 if m.shared_d_ff:
                     t += 3 * d * m.shared_d_ff
                     a += 3 * d * m.shared_d_ff
@@ -274,9 +332,12 @@ def make_smoke(cfg: ModelConfig, *, d_model: int = 128, vocab: int = 512) -> Mod
     """Reduced variant of the same family: <=2 pattern blocks, 1 repeat,
     d_model<=512, <=4 experts. Used by per-arch CPU smoke tests."""
 
-    def shrink_attn(a: Optional[AttnSpec]) -> Optional[AttnSpec]:
+    def shrink_attn(a):
         if a is None:
             return None
+        if isinstance(a, MLASpec):
+            return replace(a, n_heads=4, kv_lora_rank=32, qk_nope_head_dim=16,
+                           qk_rope_head_dim=8, v_head_dim=16)
         return replace(
             a, n_heads=4, n_kv_heads=2 if a.n_kv_heads < a.n_heads else 4, head_dim=32
         )
@@ -291,6 +352,8 @@ def make_smoke(cfg: ModelConfig, *, d_model: int = 128, vocab: int = 512) -> Mod
                 d_ff=64,
                 shared_d_ff=64 if b.moe.shared_d_ff else 0,
                 capacity_factor=2.0,
+                num_held=(None if b.moe.num_held is None else
+                          max(1, 4 * b.moe.num_held // b.moe.num_experts)),
             )
         ssm = None
         if b.ssm is not None:

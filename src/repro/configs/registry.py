@@ -26,6 +26,8 @@ _MODULES = {
     "phi35-moe": "repro.configs.phi35_moe",
     # reduced reproduction workhorse
     "olmoe-mini": "repro.configs.olmoe_mini",
+    # latent attention (MLA) with DeepSeekMoE
+    "deepseek-v2-lite": "repro.configs.deepseek_v2_lite",
 }
 
 ASSIGNED = tuple(list(_MODULES)[:10])

@@ -32,7 +32,7 @@ def init_lora(key, cfg: ModelConfig, spec: MelinoeSpec, dtype=jnp.float32):
             b = cfg.block_defs[bname]
             if b.moe is None:
                 continue
-            E, d, f = b.moe.num_experts, cfg.d_model, b.moe.d_ff
+            E, d, f = b.moe.held, cfg.d_model, b.moe.d_ff  # experts held here
             dims = {"wu": (d, f), "wd": (f, d)}
             ptree = {}
             for t in LORA_TARGETS:

@@ -48,7 +48,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..configs.base import BlockSpec, ModelConfig
+from ..configs.base import BlockSpec, MLASpec, ModelConfig
 from ..models.blocks import apply_block_full
 from ..models.common import rms_norm, silu
 from ..models.mlp import apply_mlp
@@ -449,6 +449,15 @@ class OffloadedMoEEngine:
     ):
         assert cfg.has_router, "offload engine needs an MoE architecture"
         assert impl in ("slab", "dict"), impl
+        for name, b in cfg.block_defs.items():
+            if isinstance(b.attn, MLASpec):
+                raise NotImplementedError(
+                    f"offload engine: block {name!r} has MLASpec attention "
+                    "(a latent KV cache), which the engine does not run")
+            if b.moe is not None and b.moe.held < b.moe.num_experts:
+                raise NotImplementedError(
+                    f"offload engine: block {name!r} holds a share of its "
+                    f"experts ({b.moe.held} of {b.moe.num_experts})")
         self.cfg = cfg
         self.rt = Runtime(zero_drop=True, kernel_backend=kernel_backend)
         self.kernel_backend = kernel_backend

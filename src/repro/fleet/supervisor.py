@@ -397,12 +397,20 @@ class FleetSupervisor:
         self._drain_requested = True
 
     def drain(self) -> None:
-        """Forward SIGTERM, wait for graceful exits, SIGKILL stragglers."""
+        """Forward SIGTERM, wait for graceful exits, SIGKILL stragglers.
+
+        A worker is signalled once its own first heartbeat is out: it
+        installs its SIGTERM handler before that beat, and one still
+        importing would die of the signal with its requests unjournaled."""
+        deadline = time.time() + self.cfg.drain_timeout_s
         for w in self.workers:
             if w.live and w.proc is not None and w.proc.poll() is None:
+                while (w.proc.poll() is None and time.time() < deadline
+                       and (read_heartbeat(w.dir / HEARTBEAT_NAME)
+                            or {}).get("pid") != w.proc.pid):
+                    time.sleep(0.05)
                 w.proc.send_signal(signal.SIGTERM)
                 self._event(w.idx, "sigterm")
-        deadline = time.time() + self.cfg.drain_timeout_s
         for w in self.workers:
             if w.proc is None:
                 continue

@@ -119,15 +119,17 @@ def main(argv=None) -> int:
                          "doesn't re-fire forever)")
     args = ap.parse_args(argv)
 
+    # the handler goes in before the first beat: the supervisor holds a
+    # drain's SIGTERM until this incarnation's first beat is out
+    drain = {"flag": False}
+    signal.signal(signal.SIGTERM,
+                  lambda *_: drain.__setitem__("flag", True))
+
     spec = json.loads(Path(args.spec).read_text(encoding="utf-8"))
     wdir = Path(spec.get("dir") or Path(args.spec).parent)
     hb = HeartbeatWriter(wdir / HEARTBEAT_NAME)
     hb.beat(phase="init")
     hb_s = float(spec.get("heartbeat_s", 0.25))
-
-    drain = {"flag": False}
-    signal.signal(signal.SIGTERM,
-                  lambda *_: drain.__setitem__("flag", True))
 
     # fault plan: only what the spec says — a leaked REPRO_FAULTS env
     # var (already auto-installed at import) must not fault a worker

@@ -26,7 +26,7 @@ import jax
 
 # Every kernel family registered with the dispatcher. Consumers ask for
 # one of these names; unknown names are an error so typos fail loudly.
-OPS = ("flash_attn", "int4_matmul", "moe_gmm", "ssd_scan")
+OPS = ("flash_attn", "int4_matmul", "mla_decode", "moe_gmm", "ssd_scan")
 
 BACKENDS = ("ref", "pallas", "auto")
 

@@ -7,12 +7,15 @@ chunks are visited per q chunk (no masked-rectangle waste — unlike the
 pure-JAX fallback, which computes the full rectangle under scan).
 
 Supports: GQA (G q-heads per kv head processed together as a (G*bq, hd)
-block), score softcap (gemma2), sliding-window masking.
+block), score softcap (gemma2), sliding-window masking, a value width of
+its own and an explicit softmax scale (latent attention: q/k 192 wide,
+v 128).
 
-Layouts: q (B, T, Hkv, G, hd); k/v (B, S, Hkv, hd); out like q. Inside
+Layouts: q (B, T, Hkv, G, hd); k (B, S, Hkv, hd); v (B, S, Hkv, hv);
+out (B, T, Hkv, G, hv). Inside
 the call the kv-head axis leads the token axis, so every block's last
-two dims are (rows, hd): the rows are a multiple of 8 and hd is the
-whole dim, as the TPU's (8, 128) tiling requires. ``T`` and ``S`` must
+two dims are (rows, width): the rows are a multiple of 8 and the width is
+the whole dim, as the TPU's (8, 128) tiling requires. ``T`` and ``S`` must
 be multiples of ``bq`` and ``bk`` (``ops.py`` pads and slices back).
 """
 from __future__ import annotations
@@ -79,22 +82,26 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 def flash_attention_fwd(
     q: jax.Array,  # (B, T, Hkv, G, hd)
     k: jax.Array,  # (B, S, Hkv, hd)
-    v: jax.Array,
+    v: jax.Array,  # (B, S, Hkv, hv)
     *,
     softcap: Optional[float] = None,
     window: Optional[int] = None,
     bq: int = 256,
     bk: int = 256,
     interpret: bool = False,
+    scale: Optional[float] = None,
 ) -> jax.Array:
+    """Scores are scaled by ``scale``, by default ``hd**-0.5``."""
     B, T, Hkv, G, hd = q.shape
     S = k.shape[1]
+    hv = v.shape[-1]
     if T % bq or S % bk or bk % 8 or (bq * G) % 8:
         raise ValueError(f"unaligned flash tiles: T={T} bq={bq} S={S} "
                          f"bk={bk} G={G}")
     n_q = T // bq
     n_kv = S // bk
-    scale = hd**-0.5
+    if scale is None:
+        scale = hd**-0.5
     # kv-head-major, token-major rows: the MXU sees one (bq*G, hd) matmul
     # per chunk; row index = t*G + g
     qf = q.transpose(0, 2, 1, 3, 4).reshape(B, Hkv, T * G, hd)
@@ -111,19 +118,19 @@ def flash_attention_fwd(
         in_specs=[
             pl.BlockSpec((1, 1, bq * G, hd), lambda b, h, i: (b, h, i, 0)),
             pl.BlockSpec((1, 1, S, hd), lambda b, h, i: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, S, hd), lambda b, h, i: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, S, hv), lambda b, h, i: (b, h, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, bq * G, hd), lambda b, h, i: (b, h, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, T * G, hd), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, bq * G, hv), lambda b, h, i: (b, h, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, T * G, hv), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((bq * G, 1), jnp.float32),
             pltpu.VMEM((bq * G, 1), jnp.float32),
-            pltpu.VMEM((bq * G, hd), jnp.float32),
+            pltpu.VMEM((bq * G, hv), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")),
         interpret=interpret,
         name="flash_attn",
     )(qf, kf, vf)
-    # (B, Hkv, T*G, hd) -> (B, T, Hkv, G, hd)
-    return out.reshape(B, Hkv, T, G, hd).transpose(0, 2, 1, 3, 4)
+    # (B, Hkv, T*G, hv) -> (B, T, Hkv, G, hv)
+    return out.reshape(B, Hkv, T, G, hv).transpose(0, 2, 1, 3, 4)

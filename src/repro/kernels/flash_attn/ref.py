@@ -9,12 +9,13 @@ import jax.numpy as jnp
 
 
 def attention_ref(q, k, v, *, softcap: Optional[float] = None,
-                  window: Optional[int] = None):
-    """q (B,T,Hkv,G,hd); k/v (B,S,Hkv,hd) -> (B,T,Hkv,G,hd)."""
+                  window: Optional[int] = None, scale: Optional[float] = None):
+    """q (B,T,Hkv,G,hd); k (B,S,Hkv,hd); v (B,S,Hkv,hv) ->
+    (B,T,Hkv,G,hv). Scores are scaled by ``scale``, default hd**-0.5."""
     B, T, Hkv, G, hd = q.shape
     S = k.shape[1]
     s = jnp.einsum("bqhgd,bkhd->bhgqk", q.astype(jnp.float32),
-                   k.astype(jnp.float32)) * hd**-0.5
+                   k.astype(jnp.float32)) * (hd**-0.5 if scale is None else scale)
     if softcap is not None:
         s = jnp.tanh(s / softcap) * softcap
     qp = jnp.arange(T)[:, None]

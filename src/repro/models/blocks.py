@@ -7,12 +7,14 @@ from typing import Any, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from ..configs.base import BlockSpec, ModelConfig
+from ..configs.base import BlockSpec, MLASpec, ModelConfig
 from . import attention as attn_mod
 from . import mamba2 as mamba_mod
 from .attention import KVCache, attend_full, cache_from_prefill, decode_attend, init_attn
 from .common import rms_norm, rms_norm_init
 from .mamba2 import MambaState, apply_mamba_decode, apply_mamba_full, init_mamba
+from .mla import (init_latent_cache, init_mla, latent_cache_from_prefill,
+                  mla_decode_step, mla_full)
 from .mlp import apply_mlp, init_mlp
 from .moe import apply_moe, init_moe, router_probs
 from .runtime import Runtime
@@ -26,7 +28,8 @@ def init_block(key, cfg: ModelConfig, b: BlockSpec, dtype):
     if b.kind == "mamba":
         p["mixer"] = init_mamba(ks[0], cfg.d_model, b.ssm, dtype)
         return p
-    p["mixer"] = init_attn(ks[0], cfg.d_model, b.attn, dtype)
+    init_mixer = init_mla if isinstance(b.attn, MLASpec) else init_attn
+    p["mixer"] = init_mixer(ks[0], cfg.d_model, b.attn, dtype)
     p["ln2"] = rms_norm_init(cfg.d_model, dtype)
     if b.kind == "attn_moe":
         p["ffn"] = init_moe(ks[1], cfg.d_model, b.moe, dtype)
@@ -37,6 +40,10 @@ def init_block(key, cfg: ModelConfig, b: BlockSpec, dtype):
 
 def effective_window(b: BlockSpec, window_override: Optional[int]) -> Optional[int]:
     if b.attn is None:
+        return None
+    if isinstance(b.attn, MLASpec):
+        if window_override is not None:
+            raise NotImplementedError("MLASpec attention has no sliding window")
         return None
     w = b.attn.window
     if window_override is not None:
@@ -79,7 +86,12 @@ def apply_block_full(
         return x, aux
 
     w = effective_window(b, window_override)
-    if want_cache:
+    if isinstance(b.attn, MLASpec):
+        y, (c_kv, k_pe) = mla_full(params["mixer"], b.attn, h, positions, rt)
+        if want_cache:
+            aux["kv"] = latent_cache_from_prefill(c_kv, k_pe, b.attn,
+                                                  cache_slots or c_kv.shape[1])
+    elif want_cache:
         y, (k, v) = attend_full(params["mixer"], b.attn, h, positions, w,
                                 return_kv=True, rt=rt)
         aux["kv"] = cache_from_prefill(k, v, b.attn, cache_slots or k.shape[1])
@@ -127,7 +139,10 @@ def apply_block_decode(
         return x + y, new_state, aux
 
     w = effective_window(b, window_override)
-    y, new_cache = decode_attend(params["mixer"], b.attn, h, cache, pos, w)
+    if isinstance(b.attn, MLASpec):
+        y, new_cache = mla_decode_step(params["mixer"], b.attn, h, cache, pos, rt)
+    else:
+        y, new_cache = decode_attend(params["mixer"], b.attn, h, cache, pos, w)
     x = x + y
     h2 = rms_norm(params["ln2"], x, cfg.norm_eps)
     if b.kind == "attn_moe":
@@ -153,6 +168,8 @@ def init_block_cache(cfg: ModelConfig, b: BlockSpec, batch: int, n_slots: int,
             conv=jnp.zeros((batch, s.d_conv - 1, mamba_mod.conv_dim(s, cfg.d_model)), dtype),
             ssm=jnp.zeros((batch, s.n_heads(cfg.d_model), s.head_dim, s.d_state), jnp.float32),
         )
+    if isinstance(b.attn, MLASpec):
+        return init_latent_cache(batch, n_slots, b.attn, dtype)
     w = effective_window(b, window_override)
     slots = min(n_slots, w) if w is not None else n_slots
     return attn_mod.init_kv_cache(batch, slots, b.attn, dtype)

@@ -10,6 +10,12 @@ Two execution paths share the dispatch logic:
 Dispatch is GShard-style: per-expert capacity ``cap``; overflow tokens
 are dropped (gate mass zeroed). ``zero_drop=True`` (decode) sizes the
 buffer at N tokens so nothing can drop.
+
+A layer may hold one chip's share of the experts (``MoESpec.num_held``:
+ids 0 .. held-1, the rest on other chips). The router keeps its full
+width and top-k; routes to experts not held here are dropped before
+dispatch, so the layer returns its own experts' part of the result
+(plus the shared experts, which every chip runs).
 """
 from __future__ import annotations
 
@@ -53,9 +59,9 @@ def set_opt_flags(**kw):
 
 def init_moe(key, d_model: int, spec: MoESpec, dtype):
     ks = jax.random.split(key, 5)
-    E, f = spec.num_experts, spec.d_ff
+    E, f = spec.held, spec.d_ff
     p = {
-        "router": dense_init(ks[0], d_model, E, jnp.float32),
+        "router": dense_init(ks[0], d_model, spec.num_experts, jnp.float32),
         "wg": jax.vmap(lambda k: dense_init(k, d_model, f, dtype))(
             jax.random.split(ks[1], E)
         ),
@@ -104,7 +110,7 @@ class Dispatch(NamedTuple):
 
 def make_dispatch(gates, eids, spec: MoESpec, cap: int) -> Dispatch:
     N, K = eids.shape
-    E = spec.num_experts
+    E = spec.held
     flat = eids.reshape(N * K)
     oh = jax.nn.one_hot(flat, E, dtype=jnp.int32)
     pos = (jnp.cumsum(oh, axis=0) * oh).sum(-1) - 1  # occurrences before self
@@ -182,9 +188,15 @@ def apply_moe_local(params, x2d, spec: MoESpec, rt: Runtime, lora=None,
     if probs is None:
         probs = router_probs(params, x2d, spec)
     gates, eids = top_k_route(probs, spec.top_k)
+    if spec.held < spec.num_experts:
+        # experts held on other chips: the route carries no gate here and
+        # the out-of-range id ``held`` is dropped by the dispatch scatter
+        here = eids < spec.held
+        gates = jnp.where(here, gates, 0.0)
+        eids = jnp.where(here, eids, spec.held)
     cap = _capacity(spec, x2d.shape[0], rt.zero_drop)
     d = make_dispatch(gates, eids, spec, cap)
-    buf = dispatch_tokens(d, x2d, spec.num_experts)
+    buf = dispatch_tokens(d, x2d, spec.held)
     out_buf = expert_ffn(params, buf, rt, lora, lora_scale)
     y = combine_tokens(d, out_buf)
     if spec.shared_d_ff:
@@ -199,6 +211,11 @@ def apply_moe_local(params, x2d, spec: MoESpec, rt: Runtime, lora=None,
 
 def apply_moe_sharded(params, x2d, spec: MoESpec, rt: Runtime, lora=None,
                       lora_scale: float = 1.0, probs=None):
+    if spec.held < spec.num_experts:
+        raise NotImplementedError(
+            f"the sharded MoE path spreads all {spec.num_experts} experts over "
+            f"the model axis; it does not take an expert share "
+            f"(num_held={spec.num_held})")
     mesh = rt.mesh
     ms = rt.axis_size("model")
     E = spec.num_experts

@@ -34,7 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..configs.base import ModelConfig
+from ..configs.base import MLASpec, ModelConfig
 from ..core.offload_engine import HardwareProfile, OffloadedMoEEngine
 from ..faults import FetchPolicy, get_fault_plan
 from ..inference.engine import Request, ServingEngine, truncate_at_stop
@@ -100,6 +100,9 @@ class ContinuousBatchingServer:
         self.max_len = max_len
         self.seed = seed  # recorded in recovery checkpoints
         self._key0 = jax.random.key(seed)
+        # latent-attention layers read each row's cache up to its position
+        self._latent = any(isinstance(b.attn, MLASpec)
+                           for b in cfg.block_defs.values())
 
         def _decode(params, tokens, cache):
             return decode_step(
@@ -340,8 +343,14 @@ class ContinuousBatchingServer:
                 plan.maybe_crash("serve.decode")
 
             # -- one fused decode step over the whole slot pool ---------
+            # cached_positions: what one latent-attention layer reads over
+            # the active rows, each its position (prompt + generated - 1)
+            # plus one; 0 where no layer reads a latent cache
+            cached = (sum(state.slots[s].request.prompt_len
+                          + len(state.slots[s].generated) for s in active)
+                      if self._latent else 0)
             with clock_span("serve.decode_step", active=len(active),
-                            slots=self.n_slots) as cs:
+                            slots=self.n_slots, cached_positions=cached) as cs:
                 with tr.span("serve.decode_step.dispatch"):
                     logits, self.cache, _ = self._decode_jit(
                         self.params, jnp.asarray(cur), self.cache

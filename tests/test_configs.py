@@ -18,6 +18,8 @@ EXPECT = {
     "internvl2-76b": dict(L=80, d=8192, H=64, kv=8, vocab=128256, family="vlm"),
     "qwen3-4b": dict(L=36, d=2560, H=32, kv=8, vocab=151936, family="dense"),
     "mamba2-130m": dict(L=24, d=768, vocab=50280, family="ssm"),
+    "deepseek-v2-lite": dict(L=27, d=2048, vocab=102400, family="moe", E=64, K=6,
+                             mla=(16, 512, 128, 64, 128)),
 }
 
 
@@ -35,6 +37,10 @@ def test_assigned_configs_exact(arch):
     if "E" in e:
         assert cfg.moe_spec.num_experts == e["E"]
         assert cfg.moe_spec.top_k == e["K"]
+    if "mla" in e:  # latent attention: heads, latent, q/k nope + rope, v
+        a = next(b.attn for b in cfg.block_defs.values())
+        assert (a.n_heads, a.kv_lora_rank, a.qk_nope_head_dim, a.qk_rope_head_dim,
+                a.v_head_dim) == e["mla"]
     assert cfg.source  # every config cites its source
 
 

@@ -356,3 +356,28 @@ def test_fleet_supervisor_sigterm_drains_exit_zero(tmp_path):
             == report["n_requests"])
     for w in report["workers"]:
         assert w["exit_code"] == 0, (w, stdout)
+
+
+def test_drain_holds_sigterm_until_a_starting_worker_has_beaten(tmp_path):
+    """A worker still importing has no SIGTERM handler: the drain waits
+    for its first heartbeat (written once the handler is in) before it
+    forwards the signal, so the worker drains instead of dying of it."""
+    sup = FleetSupervisor([], FleetConfig(n_workers=1), tmp_path)
+    sup.setup()
+    w = sup.workers[0]
+    hb_path = w.dir / HEARTBEAT_NAME
+    code = (
+        "import signal, time\n"
+        "from repro.fleet.heartbeat import HeartbeatWriter\n"
+        "time.sleep(1.0)\n"  # still starting up: the default action kills
+        "got = []\n"
+        "signal.signal(signal.SIGTERM, lambda *_: got.append(1))\n"
+        f"HeartbeatWriter({str(hb_path)!r}).beat(phase='init')\n"
+        "while not got:\n"
+        "    time.sleep(0.01)\n")
+    w.proc = subprocess.Popen([sys.executable, "-c", code],
+                              env=subproc_env())
+    sup.drain()
+    assert w.exit_code == 0
+    assert read_heartbeat(hb_path)["pid"] == w.proc.pid
+    assert [e["event"] for e in sup.events] == ["sigterm"]

@@ -157,3 +157,60 @@ def test_flash_attn_vs_ref(B, T, Hkv, G, hd, cap, win, bq, bk):
     out = flash(q, k, v, softcap=cap, window=win, bq=bq, bk=bk, interpret=True)
     ref = attention_ref(q, k, v, softcap=cap, window=win)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=1e-4)
+
+
+@pytest.mark.parametrize("hd,hv,scale", [(192, 128, 0.114721), (24, 16, None)])
+def test_flash_attn_value_width_and_scale_vs_ref(hd, hv, scale):
+    """Latent attention's prefill: q/k wider than v, an explicit scale."""
+    q = jax.random.normal(jax.random.key(0), (1, 40, 2, 1, hd))
+    k = jax.random.normal(jax.random.key(1), (1, 40, 2, hd))
+    v = jax.random.normal(jax.random.key(2), (1, 40, 2, hv))
+    out = flash(q, k, v, bq=16, bk=16, interpret=True, scale=scale)
+    ref = attention_ref(q, k, v, scale=scale)
+    assert out.shape == (1, 40, 2, 1, hv)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=1e-4)
+
+
+def test_flash_attn_default_scale_is_unchanged_at_equal_widths():
+    q = jax.random.normal(jax.random.key(0), (2, 64, 2, 2, 16))
+    k = jax.random.normal(jax.random.key(1), (2, 64, 2, 16))
+    v = jax.random.normal(jax.random.key(2), (2, 64, 2, 16))
+    plain = flash(q, k, v, bq=16, bk=16, interpret=True)
+    named = flash(q, k, v, bq=16, bk=16, interpret=True, scale=16**-0.5)
+    np.testing.assert_array_equal(np.asarray(plain), np.asarray(named))
+    np.testing.assert_allclose(np.asarray(plain),
+                               np.asarray(attention_ref(q, k, v)), atol=2e-5, rtol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# Latent-attention decode
+# ---------------------------------------------------------------------------
+
+from repro.kernels.mla_decode import mla_decode_pallas, mla_decode_ref
+
+
+@pytest.mark.parametrize("S,pos", [(24, [0, 7, 23]), (1536, [3, 600, 1535]),
+                                   (1024, [1100, 511, 512])],
+                         ids=["one_block", "ragged", "wrapped"])
+def test_mla_decode_vs_ref(S, pos):
+    """Rows at different positions (a block boundary, the last slot, and
+    one past the cache's length, which reads every slot), with unwritten
+    slots (``slot_pos`` -1) among the positions a row reads."""
+    B, H, R, P = 3, 4, 32, 8
+    ks = jax.random.split(jax.random.key(0), 4)
+    q = jax.random.normal(ks[0], (B, H, R))
+    q_pe = jax.random.normal(ks[1], (B, H, P))
+    c_kv = jax.random.normal(ks[2], (B, S, R))
+    k_pe = jax.random.normal(ks[3], (B, S, P))
+    pos = jnp.asarray(pos, jnp.int32)
+    at = jnp.arange(S)[None]
+    written = jnp.where(pos[:, None] < S, at, pos[:, None] - (pos[:, None] - at) % S)
+    slot_pos = jnp.where(written <= pos[:, None], written, -1).astype(jnp.int32)
+    slot_pos = slot_pos.at[:, 1].set(-1)  # a slot never written
+    # what a row must not read: past its position, and the unwritten slot
+    c_kv = jnp.where((slot_pos < 0)[..., None], 1e4, c_kv)
+    out = mla_decode_pallas(q, q_pe, c_kv, k_pe, slot_pos, pos, scale=0.3,
+                            interpret=True)
+    ref = mla_decode_ref(q, q_pe, c_kv, k_pe, slot_pos, pos, scale=0.3)
+    assert out.shape == (B, H, R) and float(jnp.abs(ref).max()) < 10
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=1e-4)

@@ -11,7 +11,7 @@ from repro.launch.steps import build_train_step
 from repro.models import Runtime, apply_model, decode_step, init_params, prefill
 from repro.training.optim import OptConfig, init_opt_state
 
-ALL = list(ASSIGNED) + ["olmoe", "mixtral-8x7b", "phi35-moe"]
+ALL = list(ASSIGNED) + ["olmoe", "mixtral-8x7b", "phi35-moe", "deepseek-v2-lite"]
 
 
 def make_batch(cfg, B=2, T=24, seed=1):
@@ -61,7 +61,8 @@ def test_smoke_train_step(arch):
 
 
 @pytest.mark.parametrize("arch", ["qwen3-4b", "zamba2-7b", "mamba2-130m",
-                                  "deepseek-moe-16b", "gemma2-27b"])
+                                  "deepseek-moe-16b", "gemma2-27b",
+                                  "deepseek-v2-lite"])
 def test_smoke_decode_step(arch):
     cfg = get_config(arch + "-smoke")
     rt = Runtime(zero_drop=True)

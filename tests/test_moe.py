@@ -123,3 +123,85 @@ def test_lora_delta_changes_expert_output():
     zl = jax.tree.map(jnp.zeros_like, lora)
     y2, _ = apply_moe_local(params, x, spec, rt, lora=zl, lora_scale=1.0)
     np.testing.assert_allclose(np.asarray(y0), np.asarray(y2), atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# One chip's share of the experts
+# ---------------------------------------------------------------------------
+
+
+def _share(params, spec, first, held):
+    """The layer as the chip holding experts ``first .. first+held-1``
+    runs it: its own experts, and the router's outputs renumbered so that
+    they come first (the router keeps every output)."""
+    from dataclasses import replace
+
+    p = dict(params, router=jnp.roll(params["router"], -first, axis=1),
+             **{w: params[w][first:first + held] for w in ("wg", "wu", "wd")})
+    return p, replace(spec, num_held=held)
+
+
+@pytest.mark.parametrize("backend", ["ref", "auto"])
+def test_two_expert_shares_add_up_to_the_uncut_layer(backend):
+    """Experts 0-3 on one chip and 4-7 on the other, each routing over all
+    8 with top-3: their parts, with the shared expert that both run
+    counted once, add up to the layer holding all 8."""
+    spec = MoESpec(num_experts=8, top_k=3, d_ff=16, num_shared=1, shared_d_ff=16)
+    d = 12
+    params = init_moe(jax.random.key(0), d, spec, jnp.float32)
+    x = jax.random.normal(jax.random.key(1), (20, d))
+    rt = Runtime(zero_drop=True, kernel_backend=backend)
+    whole, _ = apply_moe_local(params, x, spec, rt)
+    from repro.models.mlp import apply_mlp
+
+    shared = apply_mlp(params["shared"], x)
+    parts = [apply_moe_local(p, x, s, rt)[0]
+             for p, s in (_share(params, spec, first, 4) for first in (0, 4))]
+    np.testing.assert_allclose(np.asarray(parts[0] + parts[1] - shared),
+                               np.asarray(whole), atol=1e-5, rtol=1e-4)
+    # each share alone misses what the other chip's experts add
+    assert float(jnp.abs(parts[0] - whole).max()) > 1e-3
+
+
+def test_every_expert_held_traces_no_share_mask():
+    """With every expert held the layer builds the computation it built
+    before shares existed: naming the count changes nothing, and only a
+    share adds the comparison with the held count and the two selects
+    that drop other chips' routes."""
+    from collections import Counter
+    from dataclasses import replace
+
+    spec = MoESpec(num_experts=8, top_k=2, d_ff=16)
+    d = 12
+    params = init_moe(jax.random.key(0), d, spec, jnp.float32)
+    x = jax.random.normal(jax.random.key(1), (6, d))
+    rt = Runtime(zero_drop=True)
+
+    def jaxpr(s, p):
+        return jax.make_jaxpr(lambda p, x: apply_moe_local(p, x, s, rt))(p, x)
+
+    def prims(j):  # primitives, inside nested jits too
+        out = Counter()
+        for e in j.jaxpr.eqns:
+            out[e.primitive.name] += 1
+            if "jaxpr" in e.params:
+                out += prims(e.params["jaxpr"])
+        return out
+
+    full = jaxpr(spec, params)
+    assert str(jaxpr(replace(spec, num_held=8), params)) == str(full)
+    p4, s4 = _share(params, spec, 0, 4)
+    share = prims(jaxpr(s4, p4))
+    extra = share - prims(full)
+    assert extra["lt"] == 1 and extra["select_n"] == 2
+    assert not prims(full) - share
+
+
+def test_the_sharded_path_refuses_an_expert_share():
+    from repro.models.moe import apply_moe_sharded
+
+    spec = MoESpec(num_experts=8, top_k=2, d_ff=16, num_held=4)
+    params = init_moe(jax.random.key(0), 12, spec, jnp.float32)
+    x = jax.random.normal(jax.random.key(1), (6, 12))
+    with pytest.raises(NotImplementedError, match="expert share"):
+        apply_moe_sharded(params, x, spec, Runtime(zero_drop=True))
